@@ -14,10 +14,15 @@ import time
 from typing import Callable, Dict, List, Tuple
 
 import numpy as np
-import jax
-import jax.numpy as jnp
 
-from repro.core import annealing, composite, genetic, instances, qap
+from repro.compile_cache import enable_compile_cache
+
+enable_compile_cache()
+
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from repro.core import annealing, composite, genetic, instances, qap  # noqa: E402
 
 SCALE = float(os.environ.get("REPRO_BENCH_SCALE", "0.02"))
 RUNS = int(os.environ.get("REPRO_BENCH_RUNS", "3"))   # paper: 10

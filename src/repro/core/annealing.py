@@ -270,7 +270,9 @@ def resolved_loop(cfg: SAConfig, n: Optional[int] = None) -> str:
     the chain state) resident in VMEM, so above the dense kernel cap
     (``kernel_ops.fused_step_fits``) — and for sparse flows, which the
     fused kernel does not stream — it degrades to the bitwise-equivalent
-    unfused ``"event"`` loop; nothing regresses at n=4096.
+    unfused ``"event"`` loop; nothing regresses at n=4096.  On a TPU
+    backend a loop that would run fused raises instead: the fused kernel
+    does not compile there (``kernel_ops.check_fused_backend``).
     """
     if cfg.loop not in ("event", "scan", "fused"):
         raise ValueError(f"unknown hot-loop realisation {cfg.loop!r}")
@@ -280,6 +282,7 @@ def resolved_loop(cfg: SAConfig, n: Optional[int] = None) -> str:
         return "event"
     if n is not None and not kernel_ops.fused_step_fits(n):
         return "event"
+    kernel_ops.check_fused_backend()
     return "fused"
 
 

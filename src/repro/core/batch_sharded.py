@@ -34,7 +34,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from . import annealing, composite, genetic, qap
+from . import annealing, composite, genetic, mapping, qap
 from .distributed import shard_map
 
 Array = jax.Array
@@ -178,3 +178,32 @@ def run_pca_batch_sharded(Cs: Array, Ms: Array, keys: Array,
     mesh axis (see :func:`run_psa_batch_sharded` for the contract)."""
     return _dispatch_sharded("pca", cfg, num_processes, True,
                              Cs, Ms, keys, n_valid, init_perm, mesh, axis)
+
+
+@functools.lru_cache(maxsize=None)
+def _sharded_polish(rounds: int, mesh: Mesh, axis: str):
+    """Jitted shard_map of ``mapping.polish_batch``: each device polishes
+    its local slice of the wave.  Polishing the solvers' sharded output
+    with the plain jitted polish would ask XLA to partition the Pallas
+    delta kernel inside it, which it cannot do."""
+    spec = P(axis)
+    return jax.jit(shard_map(
+        lambda c, m, p, k, nv: mapping.polish_batch(c, m, p, k, rounds, nv),
+        mesh=mesh, in_specs=(spec,) * 5, out_specs=(spec, spec)))
+
+
+def polish_batch_sharded(Cs: Array, Ms: Array, ps: Array, keys: Array,
+                         rounds: int, n_valid: Array, *, mesh: Mesh,
+                         axis: str = DEFAULT_AXIS) -> Tuple[Array, Array]:
+    """``mapping.polish_batch`` with the instance axis sharded over
+    ``mesh.shape[axis]`` devices (see :func:`run_psa_batch_sharded` for
+    the contract); entry b is bitwise equal to the unsharded polish."""
+    if axis not in mesh.shape:
+        raise ValueError(
+            f"mesh has no axis {axis!r}; axes: {tuple(mesh.shape)}")
+    Cs, Ms, keys, n_valid, ps, B = pad_to_mesh_multiple(
+        Cs, Ms, keys, n_valid, ps, int(mesh.shape[axis]))
+    p, f = _sharded_polish(rounds, mesh, axis)(
+        jnp.asarray(Cs), jnp.asarray(Ms), jnp.asarray(ps),
+        jnp.asarray(keys), jnp.asarray(n_valid))
+    return p[:B], f[:B]

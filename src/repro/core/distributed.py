@@ -20,30 +20,21 @@ batched delta evaluation through ``kernels.ops``) as the single-host path.
 """
 from __future__ import annotations
 
-import inspect
 from typing import Tuple
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-try:  # jax >= 0.6 re-exports shard_map at the top level
-    from jax import shard_map as _shard_map
-except ImportError:  # older jax keeps it under experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 from . import annealing, genetic
 
 Array = jax.Array
 
-# the replication-check kwarg was renamed check_rep -> check_vma
-_CHECK_KW = ("check_vma" if "check_vma" in
-             inspect.signature(_shard_map).parameters else "check_rep")
-
 
 def shard_map(f, *, mesh, in_specs, out_specs, check_vma=False):
-    return _shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **{_CHECK_KW: check_vma})
+    """``jax.shard_map`` with the replication check off by default."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=check_vma)
 
 
 def _ring_perm(n: int):

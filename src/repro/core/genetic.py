@@ -292,7 +292,9 @@ def resolved_eval(cfg: GAConfig, n: Optional[int] = None) -> str:
     temporaries resident in VMEM, so above the dense kernel cap
     (``ops.fused_step_fits``) — and for sparse flows — it degrades to the
     bitwise-equivalent unfused ``"wide"`` counter-mode path; nothing
-    regresses at n=4096.
+    regresses at n=4096.  On a TPU backend a generation that would run
+    fused raises instead: the fused kernel does not compile there
+    (``ops.check_fused_backend``).
     """
     if cfg.eval not in ("wide", "island", "fused"):
         raise ValueError(f"unknown generation realisation {cfg.eval!r}")
@@ -302,6 +304,7 @@ def resolved_eval(cfg: GAConfig, n: Optional[int] = None) -> str:
         return "wide"
     if n is not None and not ops.fused_step_fits(n):
         return "wide"
+    ops.check_fused_backend()
     return "fused"
 
 

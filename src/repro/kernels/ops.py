@@ -42,8 +42,8 @@ from ..core.sparse import SparseFlows
 from . import ref
 from .qap_delta import qap_delta_pallas_batch
 from .qap_ga_step import qap_ga_step_pallas_batch
-from .qap_objective import (qap_objective_pallas_batch, MAX_KERNEL_N,
-                            _pad_to, LANE)
+from .mosaic import padded_order
+from .qap_objective import qap_objective_pallas_batch, MAX_KERNEL_N
 from .qap_sa_step import qap_sa_step_pallas_batch
 from .qap_sparse import (qap_delta_sparse_pallas_batch,
                          qap_objective_sparse_pallas_batch,
@@ -154,7 +154,7 @@ def qap_objective(C: Array, M: Array, perms: Array, *,
         return qap_objective_sparse(C, M, perms, force_pallas=force_pallas,
                                     interpret=interpret)
     n = perms.shape[-1]
-    fits = _pad_to(max(n, LANE), LANE) <= MAX_KERNEL_N
+    fits = padded_order(n) <= MAX_KERNEL_N
     if force_pallas or (_on_tpu() and fits):
         return _objective_shared(bool(interpret or not _on_tpu()))(C, M, perms)
     return ref.qap_objective_ref(C, M, perms)
@@ -235,10 +235,10 @@ def qap_delta(C: Array, M: Array, p: Array, pairs: Array, *,
     if isinstance(C, SparseFlows):
         return qap_delta_sparse(C, M, p, pairs, force_pallas=force_pallas,
                                 interpret=interpret)
-    on_tpu = _on_tpu()
-    if not (force_pallas or on_tpu):
-        return ref.qap_delta_ref(C, M, p, pairs)
-    return _delta_shared(bool(interpret or not on_tpu))(C, M, p, pairs)
+    fits = padded_order(p.shape[-1]) <= MAX_KERNEL_N
+    if force_pallas or (_on_tpu() and fits):
+        return _delta_shared(bool(interpret or not _on_tpu()))(C, M, p, pairs)
+    return ref.qap_delta_ref(C, M, p, pairs)
 
 
 # --------------------------------------------------------- fused solver steps
@@ -252,7 +252,24 @@ def fused_step_fits(n: int) -> bool:
     ``annealing.resolved_loop`` / ``genetic.resolved_eval`` fall back to
     the unfused event/wide paths — nothing regresses at n=4096.
     """
-    return _pad_to(max(n, LANE), LANE) <= MAX_KERNEL_N
+    return padded_order(n) <= MAX_KERNEL_N
+
+
+FUSED_ON_TPU_ERROR = (
+    "the fused SA/GA step kernels (kernels/qap_sa_step.py, qap_ga_step.py) "
+    "do not compile for TPU: Mosaic refuses their in-kernel gathers "
+    "(jnp.take on vectors and on rows of values: 'Only 2D gather is "
+    "supported') and their (1, n_pad) / (1,) blocks break the (8, 128) "
+    "block tiling rule.  Use SAConfig(loop='event') / GAConfig(eval='wide'),"
+    " which run the served Pallas kernels.")
+
+
+def check_fused_backend() -> None:
+    """Refuse the fused solver steps on TPU, where they do not compile
+    (:data:`FUSED_ON_TPU_ERROR`); they stay available on CPU, where the
+    lock-step references and interpret-mode kernels run."""
+    if _on_tpu():
+        raise NotImplementedError(FUSED_ON_TPU_ERROR)
 
 
 @functools.lru_cache(maxsize=None)
@@ -505,7 +522,7 @@ def qap_objective_sparse(S: SparseFlows, M: Array, perms: Array, *,
     (4096), not the dense ``MAX_KERNEL_N``.
     """
     n = perms.shape[-1]
-    fits = _pad_to(max(n, LANE), LANE) <= MAX_SPARSE_KERNEL_N
+    fits = padded_order(n) <= MAX_SPARSE_KERNEL_N
     if force_pallas or (_on_tpu() and fits):
         return _sparse_objective_shared(
             bool(interpret or not _on_tpu()))(S, M, perms)
@@ -579,7 +596,8 @@ def qap_delta_sparse(S: SparseFlows, M: Array, p: Array, pairs: Array, *,
     TPU one Pallas launch streaming four sparse rows + four M rows per
     candidate.
     """
-    on_tpu = _on_tpu()
-    if not (force_pallas or on_tpu):
-        return ref.qap_delta_sparse_ref(S, M, p, pairs)
-    return _sparse_delta_shared(bool(interpret or not on_tpu))(S, M, p, pairs)
+    fits = padded_order(p.shape[-1]) <= MAX_SPARSE_KERNEL_N
+    if force_pallas or (_on_tpu() and fits):
+        return _sparse_delta_shared(
+            bool(interpret or not _on_tpu()))(S, M, p, pairs)
+    return ref.qap_delta_sparse_ref(S, M, p, pairs)

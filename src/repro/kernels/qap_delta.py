@@ -10,15 +10,17 @@ loop dispatches: all of a temperature level's remaining candidates are
 scored against the current state in one launch instead of a depth-K
 sequential scan (docs/DESIGN.md §4).
 
-TPU adaptation: the candidate's four matrix rows (C[a,:], C[b,:], C[:,a],
+TPU adaptation: the candidate's eight matrix rows (C[a,:], C[b,:], C[:,a],
 C[:,b] via C^T, and M rows/cols for the swapped nodes u = p[a], v = p[b])
-plus its permutation row are streamed HBM->VMEM by the BlockSpec index maps
-driven from a scalar-prefetch table -- no full-matrix residency, so the
-working set is O(N) per candidate regardless of problem size; consecutive
-candidates of the same permutation reuse the resident permutation block.
-The only dynamic addressing inside the kernel body is a 1-D gather by the
-permutation (``jnp.take``), which Mosaic supports as a dynamic gather;
-correctness is validated in interpret mode against ``ref.qap_delta_ref``.
+arrive as the 8-row blocks that hold them, picked by BlockSpec index maps
+from a scalar-prefetch table and selected inside the kernel
+(``kernels/mosaic.py``) -- no full-matrix residency, so the streamed
+working set is O(N) per candidate; consecutive candidates of the same
+permutation reuse the resident permutation block.  The four M vectors are
+gathered by the permutation in one one-hot MXU matmul (an (8, n_pad) x
+(n_pad, n_pad) product), which bounds the order at ``MAX_KERNEL_N`` like
+the objective kernel.  Correctness is checked in interpret mode against
+``ref.qap_delta_ref`` and on the chip by ``chip_smoke.py``.
 """
 from __future__ import annotations
 
@@ -29,66 +31,73 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from . import mosaic
+from .qap_objective import MAX_KERNEL_N
+
 Array = jax.Array
 
-LANE = 128
 
+def _delta_kernel(info_ref,            # (4*T,) int32 scalar prefetch: a, b, u, v
+                  p_ref,               # (1, 1, n_pad) this candidate's permutation
+                  c_row_a, c_row_b,    # 8-row blocks of C holding rows a, b
+                  ct_row_a, ct_row_b,  # ... of C^T (columns of C)
+                  m_row_u, m_row_v,    # ... of M holding rows u, v
+                  mt_row_u, mt_row_v,  # ... of M^T (columns of M)
+                  out_ref,             # (1, 1, 1) f32
+                  *, n_pad: int):
+    q = 4 * pl.program_id(0)
+    a, b = info_ref[q], info_ref[q + 1]
+    u, v = info_ref[q + 2], info_ref[q + 3]
 
-def _pad_to(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m
+    row = mosaic.block_row
+    ca, cb = row(c_row_a, a), row(c_row_b, b)          # C[a, :], C[b, :]
+    cta, ctb = row(ct_row_a, a), row(ct_row_b, b)      # C[:, a], C[:, b]
+    mu, mv = row(m_row_u, u), row(m_row_v, v)          # M[u, :], M[v, :]
+    mtu, mtv = row(mt_row_u, u), row(mt_row_v, v)      # M[:, u], M[:, v]
 
+    # The node-indexed rows gathered by the current permutation, in one
+    # one-hot matmul: g[0] = M[p, v], g[1] = M[p, u], g[2] = M[v, p],
+    # g[3] = M[u, p].
+    g = mosaic.dot(mosaic.stack_rows(mtv, mtu, mv, mu),
+                   mosaic.onehot(p_ref[0], n_pad))
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, n_pad), 1)
+    mask = (lane != a) & (lane != b)
+    col = mosaic.total(jnp.where(mask, (cta - ctb) * (g[0:1] - g[1:2]), 0.0))
+    row_ = mosaic.total(jnp.where(mask, (ca - cb) * (g[2:3] - g[3:4]), 0.0))
 
-def _delta_kernel(info_ref,            # (B*K, 4) int32 scalar prefetch: a, b, u, v
-                  p_ref,               # (1, n_pad) this candidate's permutation row
-                  c_row_a, c_row_b,    # (1, n_pad) rows of C
-                  ct_row_a, ct_row_b,  # (1, n_pad) rows of C^T (= columns of C)
-                  m_row_u, m_row_v,    # (1, n_pad) rows of M
-                  mt_row_u, mt_row_v,  # (1, n_pad) rows of M^T (= columns of M)
-                  out_ref,             # (1,) f32
-                  *, n_pad: int, mat_batched: bool = False):
-    k = pl.program_id(0)
-    a = info_ref[k, 0]
-    b = info_ref[k, 1]
-
-    p = p_ref[0, :]
-    idx = jax.lax.iota(jnp.int32, n_pad)
-    mask = (idx != a) & (idx != b)
-
-    # With instance-batched matrices each row block carries a leading
-    # length-1 instance dim ((1, 1, n_pad) instead of (1, n_pad)).
-    row = (lambda r: r[0, 0, :]) if mat_batched else (lambda r: r[0, :])
-    ca = row(c_row_a).astype(jnp.float32)      # C[a, :]
-    cb = row(c_row_b).astype(jnp.float32)      # C[b, :]
-    cta = row(ct_row_a).astype(jnp.float32)    # C[:, a]
-    ctb = row(ct_row_b).astype(jnp.float32)    # C[:, b]
-    mu = row(m_row_u).astype(jnp.float32)      # M[u, :]
-    mv = row(m_row_v).astype(jnp.float32)      # M[v, :]
-    mtu = row(mt_row_u).astype(jnp.float32)    # M[:, u]
-    mtv = row(mt_row_v).astype(jnp.float32)    # M[:, v]
-
-    # Gathers of the node-indexed columns/rows by the current permutation.
-    m_p_v = jnp.take(mtv, p, axis=0)           # M[p, v]
-    m_p_u = jnp.take(mtu, p, axis=0)           # M[p, u]
-    m_v_p = jnp.take(mv, p, axis=0)            # M[v, p]
-    m_u_p = jnp.take(mu, p, axis=0)            # M[u, p]
-
-    col = jnp.where(mask, (cta - ctb) * (m_p_v - m_p_u), 0.0).sum()
-    row = jnp.where(mask, (ca - cb) * (m_v_p - m_u_p), 0.0).sum()
-
-    # Corner terms via dynamic scalar picks from the already-resident rows.
-    caa = jnp.take(cta, a)                     # C[a, a]
-    cbb = jnp.take(ctb, b)                     # C[b, b]
-    cab = jnp.take(ca, b)                      # C[a, b]
-    cba = jnp.take(cb, a)                      # C[b, a]
-    muu = jnp.take(m_p_u, a)                   # M[p[a], u] = M[u, u]
-    mvv = jnp.take(m_p_v, b)                   # M[v, v]
-    muv = jnp.take(m_p_v, a)                   # M[u, v]
-    mvu = jnp.take(m_p_u, b)                   # M[v, u]
-
+    pick = mosaic.pick
+    caa, cbb = pick(cta, a), pick(ctb, b)              # C[a, a], C[b, b]
+    cab, cba = pick(ca, b), pick(cb, a)                # C[a, b], C[b, a]
+    muu, mvv = pick(mu, u), pick(mv, v)                # M[u, u], M[v, v]
+    muv, mvu = pick(mu, v), pick(mv, u)                # M[u, v], M[v, u]
     corner = ((caa - cbb) * (mvv - muu)
               + cab * (mvu - muv)
               + cba * (muv - mvu))
-    out_ref[0] = col + row + corner
+    out_ref[0] = col + row_ + corner
+
+
+def candidate_table(pp: Array, pairs: Array) -> Array:
+    """Flat (4 * B * K,) scalar-prefetch table of (a, b, p[a], p[b]) per
+    candidate, for padded permutations ``pp`` (B, n_pad) and ``pairs``
+    (B, K, 2)."""
+    ab = pairs.astype(jnp.int32)
+    u = jnp.take_along_axis(pp, ab[..., 0], axis=1)
+    v = jnp.take_along_axis(pp, ab[..., 1], axis=1)
+    return jnp.stack([ab[..., 0], ab[..., 1], u, v], axis=-1).reshape(-1)
+
+
+def row_spec(shape, batched: bool, start: int, per_instance: int, col: int):
+    """8-row block of a (…, rows, width) array holding the row named by
+    table column ``col`` of chunk-local candidate ``i`` (candidate
+    ``start + i`` overall; ``per_instance`` candidates per instance)."""
+    width = shape[-1]
+    if batched:
+        return pl.BlockSpec(
+            (1, mosaic.SUBLANE, width),
+            lambda i, t: ((start + i) // per_instance,
+                          t[4 * i + col] // mosaic.SUBLANE, 0))
+    return pl.BlockSpec((mosaic.SUBLANE, width),
+                        lambda i, t: (t[4 * i + col] // mosaic.SUBLANE, 0))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -97,7 +106,8 @@ def qap_delta_pallas_batch(C: Array, M: Array, ps: Array, pairs: Array,
     """Leading-batch swap deltas in one launch.
 
     ps: (B, N) one permutation per batch row; pairs: (B, K, 2) candidate
-    swaps per row  ->  (B, K) f32.  One kernel launch with grid B*K;
+    swaps per row  ->  (B, K) f32.  One kernel launch with grid B*K
+    (split into a few launches when the candidate table outgrows SMEM);
     candidate q works on permutation row q // K.  C, M are either shared
     ``(N, N)`` matrices or instance-batched ``(B0, N, N)`` with ``B0``
     dividing B (rows ``r*B//B0 .. (r+1)*B//B0 - 1`` belong to instance r
@@ -111,55 +121,43 @@ def qap_delta_pallas_batch(C: Array, M: Array, ps: Array, pairs: Array,
         raise ValueError(
             f"batched C/M leading dim {C.shape[0]} must divide B={bsz}")
     rpt = (bsz // C.shape[0]) if mat_batched else 1  # perm rows per instance
-    n_pad = _pad_to(max(n, LANE), LANE)
-    pad = n_pad - n
+    n_pad = mosaic.padded_order(n)
+    if n_pad > MAX_KERNEL_N:
+        raise ValueError(f"padded N={n_pad} exceeds kernel cap {MAX_KERNEL_N}")
 
-    mat_pad = ((0, 0), (0, pad), (0, pad)) if mat_batched else \
-        ((0, pad), (0, pad))
-    Cp = jnp.pad(C.astype(jnp.float32), mat_pad)
-    Mp = jnp.pad(M.astype(jnp.float32), mat_pad)
+    Cp = mosaic.pad_matrix(C, n_pad, n_pad)
+    Mp = mosaic.pad_matrix(M, n_pad, n_pad)
     CpT = Cp.swapaxes(-2, -1)
     MpT = Mp.swapaxes(-2, -1)
-    tail = jnp.broadcast_to(jnp.arange(n, n_pad, dtype=jnp.int32), (bsz, pad))
-    pp = jnp.concatenate([ps.astype(jnp.int32), tail], axis=1)   # (B, n_pad)
+    pp = mosaic.pad_perms(ps, n_pad)                             # (B, n_pad)
+    info = candidate_table(pp, pairs)
+    pp3 = pp[:, None, :]
 
-    ab = pairs.astype(jnp.int32)
-    u = jnp.take_along_axis(pp, ab[..., 0], axis=1)              # (B, K)
-    v = jnp.take_along_axis(pp, ab[..., 1], axis=1)
-    info = jnp.stack([ab[..., 0].reshape(-1), ab[..., 1].reshape(-1),
-                      u.reshape(-1), v.reshape(-1)], axis=1)     # (B*K, 4)
-
-    if mat_batched:
-        row = lambda col: (lambda i, info_ref:
-                           (i // (k * rpt), info_ref[i, col], 0))
-        mat_block = (1, 1, n_pad)
-    else:
-        row = lambda col: (lambda i, info_ref: (info_ref[i, col], 0))
-        mat_block = (1, n_pad)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(bsz * k,),
-        in_specs=[
-            pl.BlockSpec((1, n_pad), lambda i, info_ref: (i // k, 0)),  # p row
-            pl.BlockSpec(mat_block, row(0)),                    # C[a, :]
-            pl.BlockSpec(mat_block, row(1)),                    # C[b, :]
-            pl.BlockSpec(mat_block, row(0)),                    # C^T[a, :]
-            pl.BlockSpec(mat_block, row(1)),                    # C^T[b, :]
-            pl.BlockSpec(mat_block, row(2)),                    # M[u, :]
-            pl.BlockSpec(mat_block, row(3)),                    # M[v, :]
-            pl.BlockSpec(mat_block, row(2)),                    # M^T[u, :]
-            pl.BlockSpec(mat_block, row(3)),                    # M^T[v, :]
-        ],
-        out_specs=pl.BlockSpec((1,), lambda i, info_ref: (i,)),
-    )
-    out = pl.pallas_call(
-        functools.partial(_delta_kernel, n_pad=n_pad,
-                          mat_batched=mat_batched),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((bsz * k,), jnp.float32),
-        interpret=interpret,
-    )(info, pp, Cp, Cp, CpT, CpT, Mp, Mp, MpT, MpT)
-    return out.reshape(bsz, k)
+    outs = []
+    for start, cnt in mosaic.chunks(bsz * k, 4):
+        spec = functools.partial(row_spec, Cp.shape, mat_batched, start,
+                                 k * rpt)
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(cnt,),
+            in_specs=[
+                pl.BlockSpec((1, 1, n_pad),
+                             lambda i, t, s=start: ((s + i) // k, 0, 0)),
+                spec(0), spec(1),                       # C[a, :], C[b, :]
+                spec(0), spec(1),                       # C^T[a, :], C^T[b, :]
+                spec(2), spec(3),                       # M[u, :], M[v, :]
+                spec(2), spec(3),                       # M^T[u, :], M^T[v, :]
+            ],
+            out_specs=pl.BlockSpec((1, 1, 1), lambda i, t: (i, 0, 0)),
+        )
+        outs.append(pl.pallas_call(
+            functools.partial(_delta_kernel, n_pad=n_pad),
+            grid_spec=grid_spec,
+            out_shape=jax.ShapeDtypeStruct((cnt, 1, 1), jnp.float32),
+            interpret=interpret,
+        )(info[4 * start:4 * (start + cnt)], pp3,
+          Cp, Cp, CpT, CpT, Mp, Mp, MpT, MpT))
+    return jnp.concatenate(outs).reshape(bsz, k)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

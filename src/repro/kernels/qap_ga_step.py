@@ -20,9 +20,14 @@ the unfused ``eval="wide"`` counter-mode path -- holds on integer-valued
 instances: every operator is integer arithmetic and the objective sums
 are exact in f32 regardless of padding or order (docs/DESIGN.md §13).
 
-VMEM budget per program: pop (P, n_pad) i32 + C/M + three n_pad^2 f32
-temporaries in the objective -- within ``MAX_KERNEL_N``'s cap for the
-paper's orders.
+VMEM per program: pop (P, n_pad) i32, C and M (2.25 MiB each at
+n_pad = 768, double-buffered when they follow the instance: 9 MiB) and
+the objective's three n_pad^2 f32 temporaries (6.75 MiB) -- about
+16 MiB at the cap, v5e's whole default scoped limit.  The compiler
+gives no figure of its own: Mosaic refuses the kernel's gathers and
+(1, pop) blocks first, so on TPU the fused generation is refused
+(``ops.check_fused_backend``) and the kernel runs in interpret mode
+only.
 """
 from __future__ import annotations
 
@@ -34,7 +39,8 @@ from jax.experimental import pallas as pl
 
 from ..core import ga_ops
 from . import prng
-from .qap_objective import LANE, MAX_KERNEL_N, _pad_to
+from .mosaic import padded_order
+from .qap_objective import MAX_KERNEL_N
 
 Array = jax.Array
 
@@ -151,7 +157,7 @@ def qap_ga_step_pallas_batch(C: Array, M: Array, pops: Array, fits: Array,
         raise ValueError(
             f"batched C/M leading dim {C.shape[0]} must divide B={bsz}")
     rpt = (bsz // C.shape[0]) if mat_batched else 1
-    n_pad = _pad_to(max(n, LANE), LANE)
+    n_pad = padded_order(n)
     if n_pad > MAX_KERNEL_N:
         raise ValueError(f"padded N={n_pad} exceeds kernel cap {MAX_KERNEL_N}")
     pad = n_pad - n

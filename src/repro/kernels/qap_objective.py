@@ -2,10 +2,14 @@
 
 The GA hot loop: every new descendant needs a full O(N^2) objective
 re-evaluation (the paper, S5, cites this as the GA's cost driver).  On TPU we
-adapt the CPU gather loop to the MXU: the permuted distance matrix
-``M[p][:, p]`` is computed as ``P @ M @ P^T`` with ``P = one_hot(p)`` -- two
-N x N matmuls that run on the systolic array -- followed by an elementwise
-product with the flow matrix ``C`` and a full reduction.
+adapt the CPU gather loop to the MXU.  With ``P^T = onehot(p)``
+(``P^T[j, l] = [p[l] == j]``), ``M @ P^T`` holds ``M[i, p[l]]`` and
+``P^T @ C`` moves row ``k`` of ``C`` to row ``p[k]``, so
+
+    F(p) = sum_{k,l} C[k,l] M[p[k], p[l]] = sum((M @ P^T) * (P^T @ C))
+
+-- two N x N one-hot matmuls on the systolic array (exact at HIGHEST
+precision, ``kernels/mosaic.py``), an elementwise product and a reduction.
 
 ``qap_objective_pallas_batch`` is the wide-generation entry point: perms
 ``(B, P, N)`` evaluate in **one** launch whose grid spans every
@@ -17,10 +21,14 @@ generation, or (instances x islands x offspring) for the batched solvers
 (``ops.qap_objective``) folds any outer ``vmap`` axes into the leading
 grid axis, so the kernel never runs under ``vmap``.
 
-VMEM budget per program instance: P, M, C and two N x N temporaries in f32.
-For the paper's largest order (729, padded to 768):
-5 * 768^2 * 4B = 11.8 MB < 16 MB VMEM.  Orders above ``MAX_KERNEL_N`` fall
-back to the reference implementation (handled by ops.py).
+VMEM per program: C and M (single-buffered when shared, double-buffered
+when they change with the instance) plus the one-hot and product
+temporaries, 2.25 MiB each at the cap.  Compiled for v5e at n_pad = 768
+the kernel needs 23.45 MiB of scoped VMEM with shared matrices and
+27.95 MiB with instance-batched ones (the compiler's own figures), above
+the 16 MiB default, so it raises the limit to :data:`VMEM_LIMIT_BYTES`.
+Orders above ``MAX_KERNEL_N`` fall back to the reference implementation
+(ops.py).
 
 Padding: matrices are zero-padded to a multiple of 128 (MXU lane width);
 permutations are padded with the identity on the pad range, and since the
@@ -33,32 +41,34 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from . import mosaic
 
 Array = jax.Array
 
-LANE = 128
 MAX_KERNEL_N = 768  # padded-N cap so the working set fits VMEM
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
 
 
-def _pad_to(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m
-
-
-def _objective_kernel(p_ref, c_ref, m_ref, out_ref, *, n_pad: int,
-                      mat_batched: bool):
+def _objective_kernel(p_ref, c_ref, m_ref, out_ref, *, n_pad: int):
     """One program instance == one (leading-dim, permutation) pair."""
-    p = p_ref[0, :]                                   # (n_pad,) int32
-    onehot = (p[:, None] == jax.lax.broadcasted_iota(jnp.int32, (n_pad, n_pad), 1))
-    P = onehot.astype(jnp.float32)                    # (n_pad, n_pad)
+    pt = mosaic.onehot(p_ref[0], n_pad)               # (n_pad, n_pad)
     # With batched matrices the block carries a leading length-1 instance dim.
-    M = (m_ref[0] if mat_batched else m_ref[...]).astype(jnp.float32)
-    C = (c_ref[0] if mat_batched else c_ref[...]).astype(jnp.float32)
-    # M[p][:, p] == P @ M @ P^T  (both matmuls hit the MXU).
-    PM = jax.lax.dot_general(P, M, (((1,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    PMPt = jax.lax.dot_general(PM, P, (((1,), (1,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    out_ref[0] = jnp.sum(C * PMPt)
+    M = m_ref[0] if len(m_ref.shape) == 3 else m_ref[...]
+    C = c_ref[0] if len(c_ref.shape) == 3 else c_ref[...]
+    out_ref[0] = mosaic.total(mosaic.dot(M, pt) * mosaic.dot(pt, C))
+
+
+def matrix_spec(n_pad: int, batched: bool, instance_of):
+    """BlockSpec of a whole (n_pad, n_pad) matrix per program: shared
+    matrices are fetched once and single-buffered; instance-batched ones
+    follow ``instance_of(program) -> instance``."""
+    if batched:
+        return pl.BlockSpec((1, n_pad, n_pad),
+                            lambda i: (instance_of(i), 0, 0))
+    return pl.BlockSpec((n_pad, n_pad), lambda i: (0, 0),
+                        pipeline_mode=pl.Buffered(1))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -77,38 +87,28 @@ def qap_objective_pallas_batch(C: Array, M: Array, perms: Array,
     if mat_batched and C.shape[0] != b:
         raise ValueError(
             f"batched C/M leading dim {C.shape[0]} != perms leading dim {b}")
-    n_pad = _pad_to(max(n, LANE), LANE)
+    n_pad = mosaic.padded_order(n)
     if n_pad > MAX_KERNEL_N:
         raise ValueError(f"padded N={n_pad} exceeds kernel cap {MAX_KERNEL_N}")
 
-    pad = n_pad - n
-    mat_pad = ((0, 0), (0, pad), (0, pad)) if mat_batched else \
-        ((0, pad), (0, pad))
-    Cp = jnp.pad(C.astype(jnp.float32), mat_pad)
-    Mp = jnp.pad(M.astype(jnp.float32), mat_pad)
-    # Identity on the pad range keeps perms valid permutations of 0..n_pad-1.
-    flat = perms.reshape(b * p_cnt, n)
-    pad_ids = jnp.broadcast_to(jnp.arange(n, n_pad, dtype=perms.dtype),
-                               (b * p_cnt, pad))
-    Pp = jnp.concatenate([flat, pad_ids], axis=1)
-
-    if mat_batched:
-        mat_spec = pl.BlockSpec((1, n_pad, n_pad), lambda i: (i // p_cnt, 0, 0))
-    else:
-        mat_spec = pl.BlockSpec((n_pad, n_pad), lambda i: (0, 0))
+    Cp = mosaic.pad_matrix(C, n_pad, n_pad)
+    Mp = mosaic.pad_matrix(M, n_pad, n_pad)
+    pp = mosaic.pad_perms(perms.reshape(b * p_cnt, 1, n), n_pad)
+    mat_spec = matrix_spec(n_pad, mat_batched, lambda i: i // p_cnt)
     out = pl.pallas_call(
-        functools.partial(_objective_kernel, n_pad=n_pad,
-                          mat_batched=mat_batched),
+        functools.partial(_objective_kernel, n_pad=n_pad),
         grid=(b * p_cnt,),
         in_specs=[
-            pl.BlockSpec((1, n_pad), lambda i: (i, 0)),          # this perm
+            pl.BlockSpec((1, 1, n_pad), lambda i: (i, 0, 0)),    # this perm
             mat_spec,                                            # C
             mat_spec,                                            # M
         ],
-        out_specs=pl.BlockSpec((1,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((b * p_cnt,), jnp.float32),
+        out_specs=pl.BlockSpec((1, 1, 1), lambda i: (i, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((b * p_cnt, 1, 1), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=VMEM_LIMIT_BYTES),
         interpret=interpret,
-    )(Pp, Cp, Mp)
+    )(pp, Cp, Mp)
     return out.reshape(b, p_cnt)
 
 

@@ -18,13 +18,20 @@ fused step for free.
 
 The candidate loop inside the kernel is the sequential Metropolis scan of
 ``annealing._candidate_scan`` with the O(N) swap-delta of
-``qap_delta_pallas`` inlined (full C/M/C^T/M^T resident per program --
-VMEM budget 4 * n_pad^2 * 4B, within ``MAX_KERNEL_N``'s cap).  Rejected
+``qap_delta_pallas`` inlined (full C/M/C^T/M^T resident per program).  Rejected
 candidates never mutate state, so this is bitwise-equal to the
 acceptance-event window loop for any window width; equality against
 ``ref.qap_sa_step_ref`` (and hence the unfused counter-mode host paths)
 is exact on integer-valued instances, where every f32 sum is exact in any
 summation order (docs/DESIGN.md §13).
+
+VMEM per program: the four n_pad^2 f32 matrices, 2.25 MiB each at
+n_pad = 768 and double-buffered when they follow the instance -- 18 MiB
+before any temporary, above v5e's 16 MiB default scoped limit.  The
+compiler gives no figure of its own: Mosaic refuses the kernel's
+in-kernel gathers and (1, n_pad) blocks first, so on TPU the fused loop
+is refused (``ops.check_fused_backend``) and the kernel runs in
+interpret mode only.
 """
 from __future__ import annotations
 
@@ -35,7 +42,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 from . import prng
-from .qap_objective import LANE, MAX_KERNEL_N, _pad_to
+from .mosaic import padded_order
+from .qap_objective import MAX_KERNEL_N
 
 Array = jax.Array
 
@@ -140,7 +148,7 @@ def qap_sa_step_pallas_batch(C: Array, M: Array, ps: Array, fs: Array,
         raise ValueError(
             f"batched C/M leading dim {C.shape[0]} must divide B={bsz}")
     rpt = (bsz // C.shape[0]) if mat_batched else 1
-    n_pad = _pad_to(max(n, LANE), LANE)
+    n_pad = padded_order(n)
     if n_pad > MAX_KERNEL_N:
         raise ValueError(f"padded N={n_pad} exceeds kernel cap {MAX_KERNEL_N}")
     pad = n_pad - n

@@ -19,12 +19,7 @@ from typing import Optional, Sequence, Tuple
 import numpy as np
 
 import jax
-from jax.sharding import Mesh
-
-try:  # newer jax exposes explicit axis types
-    from jax.sharding import AxisType
-except ImportError:  # older jax: positional mesh construction only
-    AxisType = None
+from jax.sharding import AxisType, Mesh
 
 
 def production_shape(multi_pod: bool = False) -> Tuple[Tuple[int, ...], Tuple[str, ...]]:
@@ -35,10 +30,7 @@ def production_shape(multi_pod: bool = False) -> Tuple[Tuple[int, ...], Tuple[st
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape, axes = production_shape(multi_pod)
-    if AxisType is not None:
-        return jax.make_mesh(shape, axes,
-                             axis_types=(AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_mesh_with_devices(devices: Sequence, shape: Tuple[int, ...],
@@ -48,15 +40,8 @@ def make_mesh_with_devices(devices: Sequence, shape: Tuple[int, ...],
 
 
 def activate_mesh(mesh: Mesh):
-    """Context manager making ``mesh`` ambient, across jax versions:
-    ``jax.set_mesh`` (new) -> ``jax.sharding.use_mesh`` -> the Mesh object
-    itself (jax <= 0.4 context-manager protocol)."""
-    if hasattr(jax, "set_mesh"):
-        return jax.set_mesh(mesh)
-    use_mesh = getattr(jax.sharding, "use_mesh", None)
-    if use_mesh is not None:
-        return use_mesh(mesh)
-    return mesh
+    """Context manager making ``mesh`` ambient (``jax.set_mesh``)."""
+    return jax.set_mesh(mesh)
 
 
 def make_local_mesh(axes: Tuple[str, ...] = ("data", "model")) -> Mesh:

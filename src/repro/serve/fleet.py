@@ -223,6 +223,26 @@ class _FleetPending:
     holders: Set[int] = field(default_factory=set)   # worker ids in flight
 
 
+def _check_devices_free() -> None:
+    """Refuse a subprocess fleet whose coordinator already holds an
+    accelerator: a chip belongs to one process at a time, so children
+    spawned after the parent initialized JAX on it would fail or hang.
+    Only asks when this process has initialized JAX (asking would
+    otherwise initialize it and take the chip)."""
+    from jax._src import xla_bridge
+    if not xla_bridge.backends_are_initialized():
+        return
+    import jax
+    platform = jax.default_backend()
+    if platform != "cpu":
+        raise RuntimeError(
+            f"transport='subprocess' needs the {platform} devices for its "
+            f"worker processes, but this coordinator process already "
+            f"initialized JAX on {platform} and holds them (a chip belongs "
+            f"to one process).  Build the fleet before any JAX use in this "
+            f"process, or use transport='thread'.")
+
+
 class EngineWorker(WorkerBase):
     """One thread-backed worker: a private ``MappingEngine`` fed waves
     through an inbox, heartbeating through the coordinator's lock.
@@ -462,11 +482,16 @@ class EngineFleet:
         self._dispatcher: Optional[threading.Thread] = None
         self._stop = False
         self._shutdown = False
+        # A child that could not start JAX (e.g. the device is held by
+        # another process): every request fails fast with it instead of
+        # waiting out heartbeat and compile grace.
+        self._fatal: Optional[BaseException] = None
         # Config/digest/grouping proxy.  Thread transport: worker 0's
         # engine (pure reads -- usable even after that worker dies).
         # Subprocess transport: a coordinator-local engine that never
-        # solves (children own the real ones).
+        # solves (children own the real ones) and never touches JAX.
         if transport == "subprocess":
+            _check_devices_free()
             self._proto = MappingEngine(**self._engine_kwargs)
         for _ in range(workers):
             self._spawn_worker_locked()
@@ -490,11 +515,15 @@ class EngineFleet:
         """AOT-precompile bucket programs.  Thread transport: jit and
         persistent compilation caches are process-wide, so one worker's
         warmup covers every worker (and every respawn).  Subprocess
-        transport: the coordinator's proto engine compiles into the
-        *persistent* cache, which children sharing the parent's cache
-        dir (the default) reload instead of recompiling."""
+        transport: one child compiles into the *persistent* cache, which
+        children sharing the parent's cache dir (the default) reload
+        instead of recompiling; the coordinator stays off JAX, so on an
+        accelerator the devices remain the children's."""
         if self.transport == "subprocess":
-            return self._proto.warmup(**kwargs)
+            with self._cond:
+                live = [w for w in self.workers if w.alive]
+                w = live[0] if live else self._spawn_worker_locked()
+            return w.warmup(kwargs)
         for w in self.workers:
             if w.alive:
                 return w.engine.warmup(**kwargs)
@@ -795,6 +824,11 @@ class EngineFleet:
         (caller holds the lock); called from every flush pump tick and
         dispatcher tick."""
         now = time.monotonic()
+        if self._fatal is not None:
+            for p in list(self._queue) + list(self._inflight):
+                if not p.resolved:
+                    self._fail_pending_locked(p, self._fatal)
+            self._queue = []
         if self.heartbeat_timeout_s is not None:
             for w in list(self.workers):
                 if not w.alive:
@@ -886,6 +920,10 @@ class EngineFleet:
         if p.resolved:
             self.stats.duplicate_results += 1
             return
+        self._fail_pending_locked(p, exc)
+
+    def _fail_pending_locked(self, p: _FleetPending,
+                             exc: BaseException) -> None:
         p.resolved = True
         self._inflight.discard(p)
         if p.future._fail(exc):

@@ -687,12 +687,20 @@ class MappingEngine:
         Cs, Ms, keys, nvs = self._dummy_wave(bucket, wave)
         ps = jnp.broadcast_to(jnp.arange(bucket, dtype=jnp.int32),
                               (wave, bucket))
-        if execute:
-            jax.block_until_ready(mapping_lib.polish_batch(
-                Cs, Ms, ps, keys, self.polish_rounds, nvs))
+        if self.mesh is not None:
+            nshard = int(self.mesh.shape[self.instance_axis])
+            Cs, Ms, keys, nvs, ps, _ = batch_sharded.pad_to_mesh_multiple(
+                Cs, Ms, keys, nvs, ps, nshard)
+            fn = batch_sharded._sharded_polish(
+                self.polish_rounds, self.mesh, self.instance_axis)
+            args = (Cs, Ms, ps, keys, nvs)
         else:
-            mapping_lib.polish_batch.lower(
-                Cs, Ms, ps, keys, self.polish_rounds, nvs).compile()
+            fn = mapping_lib.polish_batch
+            args = (Cs, Ms, ps, keys, self.polish_rounds, nvs)
+        if execute:
+            jax.block_until_ready(fn(*args))
+        else:
+            fn.lower(*args).compile()
         return 1
 
     # ------------------------------------------------------------------ API
@@ -1041,10 +1049,16 @@ class MappingEngine:
                                    jnp.stack(keys), nvs_j, ips_j)
         if self.polish_rounds > 0:
             # Same final 2-swap refinement find_mapping applies, batched and
-            # mask-aware so swaps never cross the valid/padded boundary.
+            # mask-aware so swaps never cross the valid/padded boundary;
+            # with a mesh it is sharded like the solve.
             pkeys = jnp.stack([jax.random.fold_in(k, 7) for k in keys])
-            perms, fs = mapping_lib.polish_batch(
-                Cs_j, Ms_j, perms, pkeys, self.polish_rounds, nvs_j)
+            if self.mesh is not None:
+                perms, fs = batch_sharded.polish_batch_sharded(
+                    Cs_j, Ms_j, perms, pkeys, self.polish_rounds, nvs_j,
+                    mesh=self.mesh, axis=self.instance_axis)
+            else:
+                perms, fs = mapping_lib.polish_batch(
+                    Cs_j, Ms_j, perms, pkeys, self.polish_rounds, nvs_j)
         with self._lock:
             self.stats.solver_batches += 1
             self.stats.solver_calls += B

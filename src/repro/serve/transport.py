@@ -19,10 +19,12 @@ processes:
      **length-prefixed pickle frames** over its stdin/stdout pipes
      (4-byte big-endian length + pickle payload; stderr passes through
      for tracebacks).  Parent->child frames: ``("wave", [(token, req),
-     ...])`` and ``("stop",)``; child->parent: ``("ready",)``,
-     ``("beat",)`` (a background heartbeat thread), ``("stats", batches,
-     calls)`` and per-request ``("result", token, response)`` /
-     ``("error", token, exc)``.
+     ...])``, ``("warmup", kwargs)`` and ``("stop",)``; child->parent:
+     ``("ready",)``, ``("beat",)`` (a background heartbeat thread),
+     ``("stats", batches, calls)``, per-request ``("result", token,
+     response)`` / ``("error", token, exc)``, ``("warmed", count_or_exc)``
+     and ``("fatal", exc)`` when the child cannot start JAX (typically:
+     its device is held by another process).
   3. Failure detection needs no cooperation from the child: a SIGKILL'd
      or crashed worker closes its stdout pipe (reader sees EOF), a
      corrupted stream raises :class:`FrameError` (pickle streams cannot
@@ -205,6 +207,7 @@ class SubprocessWorker(WorkerBase):
         self._wlock = threading.Lock()
         self._reader: Optional[threading.Thread] = None
         self._writer: Optional[threading.Thread] = None
+        self._warmed: Any = None             # warmup reply: count or exc
 
     # ------------------------------------------------------------ lifecycle
     def start(self) -> None:
@@ -246,6 +249,25 @@ class SubprocessWorker(WorkerBase):
             self._tokens[token] = p
             items.append((token, p.req))
         self.inbox.append(("wave", items))
+
+    def warmup(self, kwargs: Dict[str, Any]) -> int:
+        """Run ``MappingEngine.warmup(**kwargs)`` in the child and wait
+        for its program count (caller does not hold the fleet lock)."""
+        fleet = self.fleet
+        with fleet._cond:
+            self._warmed = None
+            self.inbox.append(("warmup", kwargs))
+            fleet._cond.notify_all()
+            while self._warmed is None and self.alive:
+                fleet._cond.wait(timeout=fleet.tick_s)
+            got = self._warmed
+        if got is None:
+            raise RuntimeError(f"worker {self.wid} died during warmup"
+                               + (f": {fleet._fatal}" if fleet._fatal
+                                  else ""))
+        if isinstance(got, BaseException):
+            raise got
+        return got
 
     def shutdown(self) -> None:
         with self.fleet._cond:
@@ -332,6 +354,17 @@ class SubprocessWorker(WorkerBase):
                     with fleet._cond:
                         fleet.stats.solver_batches += msg[1]
                         fleet.stats.solver_calls += msg[2]
+                elif kind == "warmed":
+                    with fleet._cond:
+                        self._warmed = msg[1]
+                        fleet._cond.notify_all()
+                elif kind == "fatal":
+                    with fleet._cond:
+                        if fleet._fatal is None:
+                            fleet._fatal = RuntimeError(
+                                f"subprocess worker {self.wid} could not "
+                                f"start JAX: {msg[1]}")
+                        fleet._cond.notify_all()
                 elif kind == "result":
                     with fleet._cond:
                         p = self._tokens.pop(msg[1], None)
@@ -394,9 +427,14 @@ def worker_main(stdin=None, stdout=None) -> int:
     import jax
     if cache_dir:
         jax.config.update("jax_compilation_cache_dir", cache_dir)
+    wlock = threading.Lock()
+    try:
+        jax.devices()               # take the device now, not mid-wave
+    except Exception as e:
+        write_frame(out, ("fatal", _portable_exc(e)), wlock)
+        return 5
     from repro.serve.mapper import MappingEngine
     engine = MappingEngine(**spec["engine_kwargs"])
-    wlock = threading.Lock()
     stop_beats = threading.Event()
     if spec.get("beats", True):
         threading.Thread(
@@ -421,6 +459,13 @@ def worker_main(stdin=None, stdout=None) -> int:
             break
         if msg[0] == "stop":
             break
+        if msg[0] == "warmup":
+            try:
+                warmed = engine.warmup(**msg[1])
+            except Exception as e:
+                warmed = _portable_exc(e)
+            write_frame(out, ("warmed", warmed), wlock)
+            continue
         _, items = msg
         if delay_s > 0:
             time.sleep(delay_s)

@@ -5,15 +5,16 @@ are solver bodies recompiled identically on every run), so the JAX
 persistent compilation cache is enabled before anything imports jax: a
 warm cache turns each compile into a disk reload.  CI persists the cache
 directory across runs (actions/cache on ``JAX_COMPILATION_CACHE_DIR``);
-locally it defaults to ``~/.cache/repro-jax-cache``.  Set
+otherwise it is the program's own in-checkout default
+(``repro.compile_cache``, ``<checkout>/.jax_cache``).  Set
 ``JAX_COMPILATION_CACHE_DIR=""`` to disable.
 """
 import os
 
+from repro.compile_cache import CHECKOUT_CACHE
+
 # Must happen before jax is imported anywhere (jax reads the env at setup).
-os.environ.setdefault(
-    "JAX_COMPILATION_CACHE_DIR",
-    os.path.join(os.path.expanduser("~"), ".cache", "repro-jax-cache"))
+os.environ.setdefault("JAX_COMPILATION_CACHE_DIR", str(CHECKOUT_CACHE))
 # Small solver programs compile in well under the 1s default threshold;
 # cache them too -- the suite compiles hundreds of them.
 os.environ.setdefault("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS", "0")

@@ -19,7 +19,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core import annealing, batch_sharded, composite, genetic
+from repro.core import (annealing, batch_sharded, composite, genetic,
+                        mapping)
 from repro.launch.mesh import make_instance_mesh
 from repro.serve.mapper import MapRequest, MappingEngine
 
@@ -63,6 +64,13 @@ def _equality_check(nshard):
         batch_sharded.run_pca_batch_sharded(
             Cs, Ms, keys, PCA_SMALL, 2, n_valid=nvs, mesh=mesh),
         composite.run_pca_batch(Cs, Ms, keys, PCA_SMALL, 2, n_valid=nvs))
+    # the engine's final polish is sharded the same way
+    ps = jnp.where(ips < 0, jnp.arange(8, dtype=jnp.int32), ips)
+    sp, sf = batch_sharded.polish_batch_sharded(Cs, Ms, ps, keys, 6, nvs,
+                                                mesh=mesh)
+    up, uf = mapping.polish_batch(Cs, Ms, ps, keys, 6, nvs)
+    np.testing.assert_array_equal(np.asarray(sp), np.asarray(up))
+    assert np.asarray(sf).tobytes() == np.asarray(uf).tobytes()
 
 
 def test_sharded_matches_unsharded_single_device():

@@ -220,6 +220,22 @@ def test_resolved_loop_vmem_routing():
         "island"
 
 
+def test_fused_refused_on_tpu(monkeypatch):
+    """On a TPU backend, where the fused kernels do not compile, a config
+    that would run fused raises a clear error instead of falling back to
+    the unfused loops; the VMEM-cap and sparse fallbacks are unchanged."""
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    with pytest.raises(NotImplementedError, match="do not compile for TPU"):
+        annealing.resolved_loop(replace(SA_SMALL, loop="fused"), 64)
+    with pytest.raises(NotImplementedError, match="do not compile for TPU"):
+        genetic.resolved_eval(replace(GA_SMALL, eval="fused"), 64)
+    assert annealing.resolved_loop(replace(SA_SMALL, loop="fused"),
+                                   4096) == "event"
+    assert genetic.resolved_eval(replace(GA_SMALL, eval="fused",
+                                         flows="sparse"), 64) == "wide"
+    assert annealing.resolved_loop(SA_SMALL, 64) == "event"
+
+
 def test_config_validation():
     C, M = instance(8, 77)
     key = jax.random.PRNGKey(0)

@@ -402,24 +402,22 @@ def test_no_pallas_call_under_vmap_on_tpu_paths(monkeypatch):
     pallas_calls are present in the trace.
     """
     from dataclasses import replace
-    from jax.interpreters import batching
-    try:
-        from jax._src.pallas.pallas_call import pallas_call_p
-    except ImportError:
-        pytest.skip("jax moved the pallas_call primitive; update the spy")
+    from jax._src.interpreters import batching
+    from jax._src.pallas.pallas_call import pallas_call_p
     from repro.core import annealing, composite, genetic, mapping
     import repro.kernels.ops as kops
     from _fixtures import SA_SMALL, GA_SMALL, PCA_SMALL
 
     monkeypatch.setattr(kops, "_on_tpu", lambda: True)
     hits = []
-    orig = batching.primitive_batchers[pallas_call_p]
+    orig = batching.fancy_primitive_batchers[pallas_call_p]
 
     def spy(*args, **kwargs):
         hits.append(1)
         return orig(*args, **kwargs)
 
-    monkeypatch.setitem(batching.primitive_batchers, pallas_call_p, spy)
+    monkeypatch.setitem(batching.fancy_primitive_batchers, pallas_call_p,
+                        spy)
 
     # jit trace caches are keyed on signatures only — a cached CPU-path
     # jaxpr from another test would bypass the patched _on_tpu (and the
@@ -438,24 +436,31 @@ def test_no_pallas_call_under_vmap_on_tpu_paths(monkeypatch):
             pca, sa=replace(pca.sa, loop="fused"),
             ga=replace(pca.ga, eval="fused"))
         Ss = sparse.from_dense(np.asarray(Cs))
-        solvers = {
-            "psa": lambda: annealing.run_psa_batch(Cs, Ms, keys, sa, procs,
-                                                   n_valid=nvs),
+        # The fused steps do not compile for TPU: there they must refuse
+        # loudly rather than trace (or silently fall back).
+        fused = {
             "psa_fused": lambda: annealing.run_psa_batch(
                 Cs, Ms, keys, replace(sa, loop="fused"), procs,
                 n_valid=nvs),
+            "pga_fused": lambda: genetic.run_pga_batch(
+                Cs, Ms, keys, replace(GA_SMALL, eval="fused"), procs,
+                n_valid=nvs),
+            "pca_fused": lambda: composite.run_pca_batch(
+                Cs, Ms, keys, pca_fused, procs, n_valid=nvs),
+        }
+        for name, fn in fused.items():
+            with pytest.raises(NotImplementedError, match="2D gather"):
+                jax.make_jaxpr(fn)()
+        solvers = {
+            "psa": lambda: annealing.run_psa_batch(Cs, Ms, keys, sa, procs,
+                                                   n_valid=nvs),
             "psa_sparse": lambda: annealing.run_psa_batch(
                 Ss, Ms, keys, replace(sa, flows="sparse"), procs,
                 n_valid=nvs),
             "pga": lambda: genetic.run_pga_batch(Cs, Ms, keys, GA_SMALL,
                                                  procs, n_valid=nvs),
-            "pga_fused": lambda: genetic.run_pga_batch(
-                Cs, Ms, keys, replace(GA_SMALL, eval="fused"), procs,
-                n_valid=nvs),
             "pca": lambda: composite.run_pca_batch(Cs, Ms, keys, pca, procs,
                                                    n_valid=nvs),
-            "pca_fused": lambda: composite.run_pca_batch(
-                Cs, Ms, keys, pca_fused, procs, n_valid=nvs),
             "polish": lambda: mapping.polish_batch(
                 Cs, Ms,
                 jnp.broadcast_to(jnp.arange(n, dtype=jnp.int32), (B, n)),

@@ -235,3 +235,49 @@ def test_per_worker_cache_dir_created_and_used(tmp_path):
     # the child populated its private compilation cache
     w0 = tmp_path / "w0"
     assert w0.is_dir() and any(w0.iterdir())
+
+
+def test_subprocess_warmup_runs_in_a_child():
+    """The coordinator's warmup compiles in a worker process (the
+    coordinator stays off the devices) and returns the child's count."""
+    with make_fleet(workers=1) as fleet:
+        # one psa program at bucket 8, wave 1 (polish_rounds=0: no polish)
+        assert fleet.warmup(batch_sizes=(1,), warm_starts=(False,)) == 1
+        reqs = make_reqs(2, seed0=300)
+        [fleet.submit(r) for r in reqs]
+        assert_bitwise_equal(fleet.flush(), single_engine_results(reqs))
+
+
+def test_child_that_cannot_start_jax_fails_requests_fast(monkeypatch):
+    """A worker whose JAX cannot start (here: an unknown platform; on a
+    chip host, a device another process holds) reports it once, and
+    every request fails with that error instead of waiting out the
+    heartbeat and compile grace."""
+    monkeypatch.setenv("JAX_PLATFORMS", "nosuch")
+    with make_fleet(workers=1, compiling_grace_s=600.0) as fleet:
+        t0 = time.monotonic()
+        fut = fleet.submit(make_reqs(1, seed0=400)[0])
+        if not fleet.running:
+            fleet.start()
+        exc = fut.exception(timeout=60)
+        assert time.monotonic() - t0 < 60
+        assert isinstance(exc, RuntimeError)
+        assert "could not start JAX" in str(exc) and "nosuch" in str(exc)
+        with pytest.raises(RuntimeError, match="could not start JAX"):
+            fleet.warmup()
+
+
+def test_subprocess_fleet_refused_when_coordinator_holds_accelerator(
+        monkeypatch):
+    """A chip belongs to one process: once this process has initialized
+    JAX on an accelerator, a subprocess fleet (whose children need the
+    devices) is refused up front with a message naming the platform."""
+    import jax
+    from jax._src import xla_bridge
+    jax.devices()                                  # backends initialized
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: True)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    with pytest.raises(RuntimeError, match="already initialized JAX on tpu"):
+        EngineFleet(transport="subprocess", workers=1, **ENGINE_KW)
+    # the thread transport shares this process's devices: still allowed
+    EngineFleet(transport="thread", workers=1, **ENGINE_KW).stop()
