@@ -104,6 +104,14 @@ class SAConfig:
                                      # (docs/DESIGN.md §10)
 
 
+class LoopCounts(NamedTuple):
+    """What the acceptance-event loop did at each temperature level of each
+    lane (instance x process x solver chain): under vmap the lanes share
+    one loop, which runs each level until its slowest lane is done."""
+    rounds: Array   # event-loop rounds the lane ran        (..., levels)
+    accepts: Array  # moves the lane accepted               (..., levels)
+
+
 class SAState(NamedTuple):
     p: Array        # current permutation per chain        (..., N)
     f: Array        # current objective                    (...,)
@@ -153,7 +161,8 @@ def _candidate_scan(C: Array, M: Array, state: SAState, pairs: Array,
     ``max_neighbors`` sequential candidate scan with acceptance cap.
     Kept verbatim as the bitwise-equality oracle for the acceptance-event
     loop (tests/test_hotloop.py) and as the old side of the
-    ``benchmarks/solver_hotloop.py`` comparison."""
+    ``benchmarks/solver_hotloop.py`` comparison.  Returns the moves it
+    accepted last."""
     def body(carry, inputs):
         p, f, best_p, best_f, successes = carry
         ab, u = inputs
@@ -168,10 +177,10 @@ def _candidate_scan(C: Array, M: Array, state: SAState, pairs: Array,
         best_f = jnp.where(better, f, best_f)
         return (p, f, best_p, best_f, successes + accept.astype(jnp.int32)), None
 
-    (p, f, best_p, best_f, _), _ = jax.lax.scan(
+    (p, f, best_p, best_f, successes), _ = jax.lax.scan(
         body, (state.p, state.f, state.best_p, state.best_f, jnp.int32(0)),
         (pairs, us))
-    return p, f, best_p, best_f
+    return p, f, best_p, best_f, successes
 
 
 _CPU_EVENT_WIDTH = 6   # empirically balances wasted re-evaluation in the
@@ -287,7 +296,7 @@ def resolved_loop(cfg: SAConfig, n: Optional[int] = None) -> str:
 
 
 def _acceptance_event_loop(C: Array, M: Array, state: SAState, pairs: Array,
-                           us: Array, cfg: SAConfig):
+                           us: Array, cfg: SAConfig, counts: bool = False):
     """Acceptance-event hot loop (``cfg.loop="event"``, the default).
 
     Each round scores a window of the remaining candidates against the
@@ -303,16 +312,19 @@ def _acceptance_event_loop(C: Array, M: Array, state: SAState, pairs: Array,
     decisions (same candidate stream, same uniforms, same deltas bitwise
     on the CPU reference path) — and therefore the results — are
     identical to ``_candidate_scan`` for every window width.
+
+    With ``counts`` the loop also counts its rounds and returns
+    ``(rounds, accepts)`` after the state; the state is bitwise the same.
     """
     k = cfg.max_neighbors
     w = resolved_event_width(cfg, state.p.shape[0])
 
     def cond(carry):
-        _, _, _, _, start, successes = carry
+        start, successes = carry[4], carry[5]
         return (start < k) & (successes < cfg.max_success)
 
     def body(carry):
-        p, f, best_p, best_f, start, successes = carry
+        p, f, best_p, best_f, start, successes = carry[:6]
         # Window [off, off+w): anchored at `start`, clamped so it never
         # reads past the candidate list; rows before `start` (possible
         # only after clamping) are masked out of the accept selection.
@@ -331,18 +343,22 @@ def _acceptance_event_loop(C: Array, M: Array, state: SAState, pairs: Array,
         best_p = jnp.where(better, p, best_p)
         best_f = jnp.where(better, f, best_f)
         start = jnp.where(fire, off + j + 1, off + w)
-        return (p, f, best_p, best_f, start, successes + fire.astype(jnp.int32))
+        out = (p, f, best_p, best_f, start,
+               successes + fire.astype(jnp.int32))
+        return out + (carry[6] + 1,) if counts else out
 
-    p, f, best_p, best_f, _, _ = jax.lax.while_loop(
-        cond, body,
-        (state.p, state.f, state.best_p, state.best_f,
-         jnp.int32(0), jnp.int32(0)))
-    return p, f, best_p, best_f
+    init = (state.p, state.f, state.best_p, state.best_f,
+            jnp.int32(0), jnp.int32(0))
+    out = jax.lax.while_loop(
+        cond, body, init + (jnp.int32(0),) if counts else init)
+    if counts:
+        return out[:4] + (out[6], out[5])
+    return out[:4]
 
 
 def temperature_step(C: Array, M: Array, state: SAState, key: Array,
                      cfg: SAConfig, beta: Array,
-                     n_valid: Optional[Array] = None) -> SAState:
+                     n_valid: Optional[Array] = None, counts: bool = False):
     """One temperature level: up to ``max_neighbors`` candidates, at most
     ``max_success`` acceptances (paper steps 2-3).
 
@@ -356,11 +372,17 @@ def temperature_step(C: Array, M: Array, state: SAState, key: Array,
     ``"counter"`` (implied by ``loop="fused"``) takes candidate pairs and
     uniforms from the portable counter stream the fused kernel replays,
     ``"host"`` keeps the original ``jax.random`` draws.  With ``n_valid``
-    candidate swaps stay inside the padded instance's valid prefix."""
+    candidate swaps stay inside the padded instance's valid prefix.
+
+    Returns the next ``SAState``; with ``counts`` (the event loop only)
+    ``(state, (rounds, accepts))``, the level's event-loop rounds and
+    accepted moves."""
     if cfg.rng not in ("host", "counter"):
         raise ValueError(f"unknown rng regime {cfg.rng!r}")
     n = state.p.shape[0]
     loop = resolved_loop(cfg, n)
+    if counts and loop != "event":
+        raise ValueError(f"loop {loop!r} keeps no counts; only 'event' does")
     if loop == "fused":
         nv = jnp.int32(n) if n_valid is None else n_valid
         p, f, best_p, best_f = kernel_ops.qap_sa_step(
@@ -378,12 +400,14 @@ def temperature_step(C: Array, M: Array, state: SAState, key: Array,
             pairs = qap.random_swap_pairs(kpair, cfg.max_neighbors, n, n_valid)
             us = jax.random.uniform(kacc, (cfg.max_neighbors,))
         if loop == "event":
-            p, f, best_p, best_f = _acceptance_event_loop(
-                C, M, state, pairs, us, cfg)
+            p, f, best_p, best_f, *tally = _acceptance_event_loop(
+                C, M, state, pairs, us, cfg, counts)
         else:
-            p, f, best_p, best_f = _candidate_scan(C, M, state, pairs, us, cfg)
+            p, f, best_p, best_f, _ = _candidate_scan(
+                C, M, state, pairs, us, cfg)
     temp = jnp.maximum(cool(state.temp, cfg, beta), cfg.t_final)
-    return SAState(p=p, f=f, best_p=best_p, best_f=best_f, temp=temp)
+    state = SAState(p=p, f=f, best_p=best_p, best_f=best_f, temp=temp)
+    return (state, tuple(tally)) if counts else state
 
 
 def _adopt_best(state: SAState, best_p: Array, best_f: Array) -> SAState:
@@ -395,13 +419,16 @@ def _adopt_best(state: SAState, best_p: Array, best_f: Array) -> SAState:
 
 
 def _chain_round(C, M, state, key, cfg: SAConfig, beta,
-                 n_valid: Optional[Array] = None):
-    """iters_per_exchange temperature steps for one chain."""
+                 n_valid: Optional[Array] = None, counts: bool = False):
+    """iters_per_exchange temperature steps for one chain; with ``counts``
+    also each level's ``(rounds, accepts)``, ``(iters_per_exchange,)``
+    each."""
     keys = jax.random.split(key, cfg.iters_per_exchange)
     def step(s, k):
-        return temperature_step(C, M, s, k, cfg, beta, n_valid), None
-    state, _ = jax.lax.scan(step, state, keys)
-    return state
+        out = temperature_step(C, M, s, k, cfg, beta, n_valid, counts)
+        return out if counts else (out, None)
+    state, tally = jax.lax.scan(step, state, keys)
+    return (state, tally) if counts else state
 
 
 def make_beta(C: Array, M: Array, key: Array, cfg: SAConfig,
@@ -444,8 +471,7 @@ def seed_chain0(C: Array, M: Array, init, chain_key: Array, cfg,
 def _psa_impl(C: Array, M: Array, key: Array, cfg: SAConfig,
               num_processes: int, exchange: bool,
               n_valid: Optional[Array],
-              init_perm: Optional[Array] = None
-              ) -> Tuple[Array, Array, Array]:
+              init_perm: Optional[Array] = None, counts: bool = False):
     """Shared PSA body for the single-instance and instance-batched paths.
 
     With ``n_valid`` the instance is treated as padded: flows touching
@@ -463,6 +489,9 @@ def _psa_impl(C: Array, M: Array, key: Array, cfg: SAConfig,
     here under jit); every objective/delta then runs the sparse O(nnz)
     dispatches.  A sparse ``C`` with ``flows="dense"`` is allowed — the
     representation alone decides the dispatch path.
+
+    With ``counts`` a fourth result holds the event loop's
+    :class:`LoopCounts`, ``(num_processes, solvers, levels)`` each.
     """
     if cfg.flows == "sparse" and not isinstance(C, sparse.SparseFlows):
         raise TypeError(
@@ -495,8 +524,10 @@ def _psa_impl(C: Array, M: Array, key: Array, cfg: SAConfig,
     def round_step(state, key):
         keys = jax.random.split(key, num_processes * cfg.solvers) \
             .reshape(num_processes, cfg.solvers, 2)
-        state = jax.vmap(jax.vmap(
-            lambda s, k: _chain_round(C, M, s, k, cfg, beta, n_valid)))(state, keys)
+        out = jax.vmap(jax.vmap(
+            lambda s, k: _chain_round(C, M, s, k, cfg, beta, n_valid,
+                                      counts)))(state, keys)
+        state, tally = out if counts else (out, None)
         gbest_f = state.best_f.min()
         flat = state.best_f.reshape(-1)
         gbest_p = state.best_p.reshape(-1, state.best_p.shape[-1])[jnp.argmin(flat)]
@@ -504,7 +535,7 @@ def _psa_impl(C: Array, M: Array, key: Array, cfg: SAConfig,
             bp = jnp.broadcast_to(gbest_p, state.p.shape)
             bf = jnp.broadcast_to(gbest_f, state.f.shape)
             state = _adopt_best(state, bp, bf)
-        return state, gbest_f
+        return state, (gbest_f, tally) if counts else gbest_f
 
     round_keys = jax.random.split(krun, cfg.num_exchanges)
     state, history = jax.lax.scan(round_step, init, round_keys)
@@ -512,7 +543,14 @@ def _psa_impl(C: Array, M: Array, key: Array, cfg: SAConfig,
     flat_f = state.best_f.reshape(-1)
     i = jnp.argmin(flat_f)
     best_p = state.best_p.reshape(-1, state.best_p.shape[-1])[i]
-    return best_p, flat_f[i], history
+    if not counts:
+        return best_p, flat_f[i], history
+    history, tally = history
+
+    def per_lane(x):      # (exchanges, P, S, iters) -> (P, S, levels)
+        x = jnp.moveaxis(x, 0, 2)
+        return x.reshape(x.shape[:2] + (-1,))
+    return best_p, flat_f[i], history, LoopCounts(*map(per_lane, tally))
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "num_processes", "exchange"))
@@ -531,12 +569,12 @@ def run_psa(C: Array, M: Array, key: Array, cfg: SAConfig,
                      init_perm)
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "num_processes", "exchange"))
+@functools.partial(jax.jit, static_argnames=("cfg", "num_processes", "exchange",
+                                             "counts"))
 def run_psa_batch(Cs: Array, Ms: Array, keys: Array, cfg: SAConfig,
                   num_processes: int = 4, exchange: bool = True,
                   n_valid: Optional[Array] = None,
-                  init_perm: Optional[Array] = None
-                  ) -> Tuple[Array, Array, Array]:
+                  init_perm: Optional[Array] = None, counts: bool = False):
     """Instance-batched PSA: a leading vmap axis over independent instances.
 
     Cs, Ms: (B, N, N) padded instances; keys: (B, 2) one PRNG key per
@@ -546,8 +584,13 @@ def run_psa_batch(Cs: Array, Ms: Array, keys: Array, cfg: SAConfig,
     (B,), history (B, num_exchanges)), where entry b equals
     ``run_psa(Cs[b], Ms[b], keys[b], ..., n_valid[b], init_perm[b])`` — the
     batch axis changes throughput, not results.
+
+    With ``counts`` (the event loop only) a fourth result holds the
+    :class:`LoopCounts` of every lane, ``(B, num_processes, solvers,
+    num_exchanges * iters_per_exchange)`` each; the first three results
+    are bitwise those of ``counts=False``.
     """
     return qap.vmap_instances(
         lambda c, m, k, nv, ip: _psa_impl(c, m, k, cfg, num_processes,
-                                          exchange, nv, ip),
+                                          exchange, nv, ip, counts),
         Cs, Ms, keys, n_valid, init_perm)

@@ -241,6 +241,14 @@ def qap_delta(C: Array, M: Array, p: Array, pairs: Array, *,
     return ref.qap_delta_ref(C, M, p, pairs)
 
 
+def delta_order(n: int) -> int:
+    """The order :func:`qap_delta` works at for dense order-``n``
+    instances: the kernel's lane-padded order where it takes the kernel
+    path, ``n`` on the reference path."""
+    n_pad = padded_order(n)
+    return n_pad if _on_tpu() and n_pad <= MAX_KERNEL_N else n
+
+
 # --------------------------------------------------------- fused solver steps
 
 def fused_step_fits(n: int) -> bool:

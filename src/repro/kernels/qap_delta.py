@@ -152,6 +152,7 @@ def qap_delta_pallas_batch(C: Array, M: Array, ps: Array, pairs: Array,
         )
         outs.append(pl.pallas_call(
             functools.partial(_delta_kernel, n_pad=n_pad),
+            name="qap_delta",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((cnt, 1, 1), jnp.float32),
             interpret=interpret,

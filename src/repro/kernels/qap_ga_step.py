@@ -181,6 +181,7 @@ def qap_ga_step_pallas_batch(C: Array, M: Array, pops: Array, fits: Array,
                           n_off=n_off, tournament=tournament,
                           p_crossover=p_crossover, p_mutation=p_mutation,
                           crossover=crossover, mat_batched=mat_batched),
+        name="qap_ga_step",
         grid=(bsz,),
         in_specs=[
             pop_spec,                                      # population

@@ -97,6 +97,7 @@ def qap_objective_pallas_batch(C: Array, M: Array, perms: Array,
     mat_spec = matrix_spec(n_pad, mat_batched, lambda i: i // p_cnt)
     out = pl.pallas_call(
         functools.partial(_objective_kernel, n_pad=n_pad),
+        name="qap_objective",
         grid=(b * p_cnt,),
         in_specs=[
             pl.BlockSpec((1, 1, n_pad), lambda i: (i, 0, 0)),    # this perm

@@ -173,6 +173,7 @@ def qap_sa_step_pallas_batch(C: Array, M: Array, ps: Array, fs: Array,
         functools.partial(_sa_step_kernel, n_pad=n_pad,
                           max_neighbors=max_neighbors,
                           max_success=max_success, mat_batched=mat_batched),
+        name="qap_sa_step",
         grid=(bsz,),
         in_specs=[
             vec_spec,                                      # p

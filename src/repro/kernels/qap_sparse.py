@@ -156,6 +156,7 @@ def qap_objective_sparse_pallas_batch(S, M: Array, ps: Array,
         outs.append(pl.pallas_call(
             functools.partial(_objective_sparse_kernel, n_pad=n_pad,
                               rows=rows),
+            name="qap_objective_sparse",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((cnt, 1, 1), jnp.float32),
             interpret=interpret,
@@ -265,6 +266,7 @@ def qap_delta_sparse_pallas_batch(S, M: Array, ps: Array, pairs: Array,
         )
         outs.append(pl.pallas_call(
             functools.partial(_delta_sparse_kernel, n_pad=n_pad),
+            name="qap_delta_sparse",
             grid_spec=grid_spec,
             out_shape=jax.ShapeDtypeStruct((cnt, 1, 1), jnp.float32),
             interpret=interpret,

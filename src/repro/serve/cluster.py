@@ -50,6 +50,8 @@ from typing import Dict, List, Optional, Sequence
 
 import numpy as np
 
+from repro.serve import telemetry
+
 POLICIES = ("compact", "first_fit")
 CANDIDATE_POLICIES = ("compact", "first_fit", "slab", "scatter")
 
@@ -230,8 +232,10 @@ class ClusterState:
             if p not in CANDIDATE_POLICIES:
                 raise ValueError(
                     f"policy {p!r} not in {CANDIDATE_POLICIES}")
-        with self._lock:
+        with telemetry.span("cluster.carve", job=telemetry.current_job(),
+                            size=size, k=k) as span, self._lock:
             free = self._free_sorted()
+            span.set(free=int(free.shape[0]))
             if free.shape[0] < size:
                 return []
             out: List[Candidate] = []
@@ -290,7 +294,7 @@ class ClusterState:
         Releasing the allocation later restores exactly the pre-wave
         occupancy."""
         nodes = np.sort(np.asarray(nodes, dtype=np.int64))
-        with self._lock:
+        with telemetry.span("cluster.promote", job=job_id), self._lock:
             held = self._reserved.get(tag)
             if held is None:
                 raise KeyError(f"tag {tag!r} has no reservation")

@@ -95,6 +95,8 @@ import numpy as np
 
 from repro.core import (annealing, batch_sharded, composite, genetic,
                         mapping as mapping_lib, multilevel)
+from repro.kernels import ops as kernel_ops
+from repro.serve import telemetry
 
 DEFAULT_BUCKETS = (32, 64, 128)
 
@@ -166,7 +168,8 @@ class MapResponse:
     n: int
     bucket: Optional[int]      # padded size (None = solved at exact size)
     cached: bool
-    seconds: float             # amortized wall time: group wall / batch_size
+    seconds: float             # the group's engine.dispatch + engine.fetch
+    #                            wall time / batch_size
     batch_size: int = 1        # requests served by the dispatch (0 = cached)
     tier: str = "default"      # solver budget tier the policy picked
     warm_start: bool = False   # solve was seeded from a near-miss cache hit
@@ -329,6 +332,23 @@ def validate_request(req: MapRequest) -> None:
             raise ValueError(f"{name} must be a real numeric matrix")
 
 
+def _keeps_counts(cfg: annealing.SAConfig, bucket: int) -> bool:
+    """Does a bucket PSA solve keep the event loop's counts?  Only the
+    event loop has them."""
+    return annealing.resolved_loop(cfg, bucket) == "event"
+
+
+def _loop_counts(counts: annealing.LoopCounts) -> Dict[str, int]:
+    """A wave's solver counts, for its ``engine.fetch`` span: the rounds
+    the batched event loop ran (at each level, as many as its slowest
+    lane), the rounds its lanes ran, the lanes and the accepted moves."""
+    rounds = np.asarray(counts.rounds)
+    levels = rounds.reshape(-1, rounds.shape[-1])
+    return {"rounds_executed": int(levels.max(axis=0).sum()),
+            "lane_rounds": int(rounds.sum()), "lanes": levels.shape[0],
+            "accepts": int(np.asarray(counts.accepts).sum())}
+
+
 def _tighten_sa(cfg: annealing.SAConfig) -> annealing.SAConfig:
     """Reduced-budget SA for the tight deadline tier (~1/4 the work)."""
     return replace(cfg,
@@ -427,6 +447,9 @@ class MappingEngine:
         self._lock = threading.RLock()          # queue / cache / stats
         self._cond = threading.Condition(self._lock)
         self._dispatch_lock = threading.Lock()  # serializes solves
+        # engine.dispatch + engine.fetch seconds of the group being solved
+        # (under the dispatch lock): its MapResponse.seconds, amortized
+        self._device_s = 0.0
         self._flusher: Optional[threading.Thread] = None
         self._stop = False
 
@@ -665,9 +688,11 @@ class MappingEngine:
             else:
                 fn.lower(*args).compile()
             return 1
+        kw = dict(n_valid=nvs, init_perm=ips)
         if algorithm == "psa":
             fn, args = annealing.run_psa_batch, (Cs, Ms, keys, sa_cfg,
                                                  self.num_processes)
+            kw["counts"] = _keeps_counts(sa_cfg, bucket)
         elif algorithm == "pga":
             fn, args = genetic.run_pga_batch, (Cs, Ms, keys, ga_cfg,
                                                self.num_processes)
@@ -676,9 +701,9 @@ class MappingEngine:
                 Cs, Ms, keys, composite.CompositeConfig(sa=sa_cfg, ga=ga_cfg),
                 self.num_processes)
         if execute:
-            jax.block_until_ready(fn(*args, n_valid=nvs, init_perm=ips))
+            jax.block_until_ready(fn(*args, **kw))
         else:
-            fn.lower(*args, n_valid=nvs, init_perm=ips).compile()
+            fn.lower(*args, **kw).compile()
         return 1
 
     def _warmup_polish(self, bucket: int, wave: int, execute: bool) -> int:
@@ -889,15 +914,25 @@ class MappingEngine:
         solves, future resolution.  The single code path used by both the
         synchronous ``flush()`` and the background flusher, so the two are
         bitwise-equivalent on the same drained set."""
-        responses: Dict[str, MapResponse] = {}
         if not pending:
-            return responses
+            return {}
+        oldest = min(p.t_submit for p in pending)
+        with telemetry.span(
+                "engine.flush", jobs=[p.req.job_id for p in pending],
+                requests=len(pending),
+                queue_wait_ms=(time.monotonic() - oldest) * 1e3) as span:
+            return self._serve(pending, raise_errors, span)
+
+    def _serve(self, pending: List[_Pending], raise_errors: bool,
+               span: telemetry.Span) -> Dict[str, MapResponse]:
+        responses: Dict[str, MapResponse] = {}
         # Cache pass + group misses by (bucket, algorithm, tier); identical
         # instances inside one wave are solved once & shared.  Runs before
         # the dispatch lock so a pure cache hit is never serialized behind
         # an unrelated in-flight solve.
         groups: Dict[Tuple[Optional[int], str, str],
                      "OrderedDict[str, List[_Pending]]"] = {}
+        hits = 0
         with self._lock:
             for p in pending:
                 if p.future.done():          # cancelled while queued: skip
@@ -908,6 +943,7 @@ class MappingEngine:
                 if hit is not None:
                     perm, objective = hit
                     self.stats.cache_hits += 1
+                    hits += 1
                     resp = self._respond(
                         p, perm, objective,
                         bucket=self._route(p.req.C.shape[0]),
@@ -919,14 +955,15 @@ class MappingEngine:
                     continue
                 g = groups.setdefault(self._group_key(p), OrderedDict())
                 g.setdefault(key, []).append(p)
+        span.set(cache_hits=hits)
         if not groups:
             return responses
         with self._dispatch_lock:
             first_error: Optional[BaseException] = None
             for (bucket, algorithm, tier), by_digest in groups.items():
                 heads = [ps[0] for ps in by_digest.values()]
+                self._device_s = 0.0
                 try:
-                    t0 = time.perf_counter()
                     with self._lock:
                         warms = [self._warm_perm(p.req) for p in heads]
                     if bucket is None:
@@ -943,7 +980,6 @@ class MappingEngine:
                         solved = self._solve_bucket(
                             bucket, algorithm, tier,
                             [p.req for p in heads], warms)
-                    seconds = time.perf_counter() - t0
                 except Exception as e:       # fail this group's futures only
                     for ps in by_digest.values():
                         for p in ps:
@@ -951,8 +987,8 @@ class MappingEngine:
                     first_error = first_error or e
                     continue
                 total = sum(len(ps) for ps in by_digest.values())
-                per_instance = seconds / max(total, 1)
-                with self._lock:
+                per_instance = self._device_s / max(total, 1)
+                with telemetry.span("engine.respond"), self._lock:
                     self.stats.warm_starts += sum(w is not None
                                                   for w in warms)
                     for key, (perm, objective), w, p0 in zip(
@@ -1029,41 +1065,55 @@ class MappingEngine:
             return out
         B = len(reqs)
         Bp = 1 << (B - 1).bit_length() if self.pad_batches else B
-        Cs = np.zeros((Bp, bucket, bucket), np.float32)
-        Ms = np.zeros((Bp, bucket, bucket), np.float32)
-        nvs = np.zeros(Bp, np.int32)
-        keys = []
-        for i, req in enumerate(reqs):
-            n = req.C.shape[0]
-            Cs[i, :n, :n] = req.C
-            Ms[i, :n, :n] = req.M
-            nvs[i] = n
-            keys.append(jax.random.PRNGKey(req.seed))
-        for j in range(B, Bp):             # dummy rows replicate instance 0
-            Cs[j], Ms[j], nvs[j] = Cs[0], Ms[0], nvs[0]
-            keys.append(jax.random.PRNGKey(0))
-        Cs_j, Ms_j, nvs_j = jnp.asarray(Cs), jnp.asarray(Ms), jnp.asarray(nvs)
-        ips = self._init_perm_batch(reqs, bucket, warms, Bp)
-        ips_j = None if ips is None else jnp.asarray(ips)
-        perms, fs = self._dispatch(algorithm, tier, Cs_j, Ms_j,
-                                   jnp.stack(keys), nvs_j, ips_j)
-        if self.polish_rounds > 0:
-            # Same final 2-swap refinement find_mapping applies, batched and
-            # mask-aware so swaps never cross the valid/padded boundary;
-            # with a mesh it is sharded like the solve.
-            pkeys = jnp.stack([jax.random.fold_in(k, 7) for k in keys])
-            if self.mesh is not None:
-                perms, fs = batch_sharded.polish_batch_sharded(
-                    Cs_j, Ms_j, perms, pkeys, self.polish_rounds, nvs_j,
-                    mesh=self.mesh, axis=self.instance_axis)
-            else:
-                perms, fs = mapping_lib.polish_batch(
-                    Cs_j, Ms_j, perms, pkeys, self.polish_rounds, nvs_j)
+        # the rows the delta evaluation works on: real processes, padding
+        # to the bucket, the kernel's lane padding, the dummy wave rows
+        with telemetry.span(
+                "engine.pack", bucket=bucket, rows=B, padded_rows=Bp,
+                orders=sum(req.C.shape[0] for req in reqs),
+                kernel_order=kernel_ops.delta_order(bucket)):
+            Cs = np.zeros((Bp, bucket, bucket), np.float32)
+            Ms = np.zeros((Bp, bucket, bucket), np.float32)
+            nvs = np.zeros(Bp, np.int32)
+            keys = []
+            for i, req in enumerate(reqs):
+                n = req.C.shape[0]
+                Cs[i, :n, :n] = req.C
+                Ms[i, :n, :n] = req.M
+                nvs[i] = n
+                keys.append(jax.random.PRNGKey(req.seed))
+            for j in range(B, Bp):         # dummy rows replicate instance 0
+                Cs[j], Ms[j], nvs[j] = Cs[0], Ms[0], nvs[0]
+                keys.append(jax.random.PRNGKey(0))
+            Cs_j, Ms_j = jnp.asarray(Cs), jnp.asarray(Ms)
+            nvs_j = jnp.asarray(nvs)
+            ips = self._init_perm_batch(reqs, bucket, warms, Bp)
+            ips_j = None if ips is None else jnp.asarray(ips)
+        with telemetry.span("engine.dispatch", algorithm=algorithm, tier=tier,
+                            path="bucket") as dispatch:
+            perms, fs, counts = self._dispatch(
+                algorithm, tier, Cs_j, Ms_j, jnp.stack(keys), nvs_j, ips_j)
+            if self.polish_rounds > 0:
+                # Same final 2-swap refinement find_mapping applies,
+                # batched and mask-aware so swaps never cross the
+                # valid/padded boundary; with a mesh it is sharded like
+                # the solve.
+                pkeys = jnp.stack([jax.random.fold_in(k, 7) for k in keys])
+                if self.mesh is not None:
+                    perms, fs = batch_sharded.polish_batch_sharded(
+                        Cs_j, Ms_j, perms, pkeys, self.polish_rounds, nvs_j,
+                        mesh=self.mesh, axis=self.instance_axis)
+                else:
+                    perms, fs = mapping_lib.polish_batch(
+                        Cs_j, Ms_j, perms, pkeys, self.polish_rounds, nvs_j)
         with self._lock:
             self.stats.solver_batches += 1
             self.stats.solver_calls += B
-        perms = np.asarray(perms)
-        fs = np.asarray(fs)
+        with telemetry.span("engine.fetch") as fetch:
+            perms = np.asarray(perms)
+            fs = np.asarray(fs)
+            if counts is not None:
+                fetch.set(**_loop_counts(counts))
+        self._device_s += dispatch.dur + fetch.dur
         out = []
         for i, req in enumerate(reqs):
             n = int(nvs[i])
@@ -1087,23 +1137,29 @@ class MappingEngine:
         M = jnp.asarray(req.M, jnp.float32)
         key = jax.random.PRNGKey(req.seed)
         ip = None if warm is None else jnp.asarray(warm, jnp.int32)
-        if algorithm == "psa":
-            p, f, _ = annealing.run_psa(C, M, key, sa_cfg,
-                                        self.num_processes, init_perm=ip)
-        elif algorithm == "pga":
-            p, f, _ = genetic.run_pga(C, M, key, ga_cfg,
-                                      self.num_processes, init_perm=ip)
-        else:
-            p, f, _ = composite.run_pca(
-                C, M, key, composite.CompositeConfig(
-                    sa=sa_cfg, ga=ga_cfg), self.num_processes, init_perm=ip)
-        if self.polish_rounds > 0:
-            p, f = mapping_lib.polish(C, M, p, jax.random.fold_in(key, 7),
-                                      self.polish_rounds)
+        with telemetry.span("engine.dispatch", algorithm=algorithm, tier=tier,
+                            path="exact") as dispatch:
+            if algorithm == "psa":
+                p, f, _ = annealing.run_psa(C, M, key, sa_cfg,
+                                            self.num_processes, init_perm=ip)
+            elif algorithm == "pga":
+                p, f, _ = genetic.run_pga(C, M, key, ga_cfg,
+                                          self.num_processes, init_perm=ip)
+            else:
+                p, f, _ = composite.run_pca(
+                    C, M, key, composite.CompositeConfig(
+                        sa=sa_cfg, ga=ga_cfg), self.num_processes,
+                    init_perm=ip)
+            if self.polish_rounds > 0:
+                p, f = mapping_lib.polish(C, M, p, jax.random.fold_in(key, 7),
+                                          self.polish_rounds)
         with self._lock:
             self.stats.solver_batches += 1
             self.stats.solver_calls += 1
-        return np.asarray(p, np.int32), float(f)
+        with telemetry.span("engine.fetch") as fetch:
+            p, f = np.asarray(p, np.int32), float(f)
+        self._device_s += dispatch.dur + fetch.dur
+        return p, f
 
     def _solve_multilevel(self, req: MapRequest) -> Tuple[np.ndarray, float]:
         """Large-bucket instances run the coarsen → map → refine pipeline
@@ -1113,23 +1169,32 @@ class MappingEngine:
         buckets stay schedulable.  The tier's solver budgets do not apply;
         ``multilevel_cfg`` governs (and is folded into the cache digest
         for these orders)."""
-        res = multilevel.solve_multilevel(
-            req.C, req.M, jax.random.PRNGKey(req.seed), self.multilevel_cfg)
+        with telemetry.span("engine.dispatch", path="multilevel") as dispatch:
+            res = multilevel.solve_multilevel(
+                req.C, req.M, jax.random.PRNGKey(req.seed),
+                self.multilevel_cfg)
+            out = np.asarray(res.perm, np.int32), float(res.objective)
         with self._lock:
             self.stats.solver_batches += 1
             self.stats.solver_calls += 1
-        return np.asarray(res.perm, np.int32), float(res.objective)
+        self._device_s += dispatch.dur
+        return out
 
     def _dispatch(self, algorithm: str, tier: str, Cs, Ms, keys, nvs, ips):
+        """Enqueue a bucket wave's solve: (perms, fs, the event loop's
+        ``LoopCounts`` or None).  Only the PSA event loop on one device
+        keeps counts."""
         sa_cfg, ga_cfg = self._tier_cfgs[tier]
         if self.mesh is not None:
             return self._dispatch_sharded(algorithm, sa_cfg, ga_cfg,
-                                          Cs, Ms, keys, nvs, ips)
+                                          Cs, Ms, keys, nvs, ips) + (None,)
         if algorithm == "psa":
-            p, f, _ = annealing.run_psa_batch(Cs, Ms, keys, sa_cfg,
-                                              self.num_processes,
-                                              n_valid=nvs, init_perm=ips)
-        elif algorithm == "pga":
+            counts = _keeps_counts(sa_cfg, Cs.shape[-1])
+            p, f, _, *tally = annealing.run_psa_batch(
+                Cs, Ms, keys, sa_cfg, self.num_processes, n_valid=nvs,
+                init_perm=ips, counts=counts)
+            return p, f, tally[0] if counts else None
+        if algorithm == "pga":
             p, f, _ = genetic.run_pga_batch(Cs, Ms, keys, ga_cfg,
                                             self.num_processes, n_valid=nvs,
                                             init_perm=ips)
@@ -1138,7 +1203,7 @@ class MappingEngine:
                 Cs, Ms, keys, composite.CompositeConfig(
                     sa=sa_cfg, ga=ga_cfg),
                 self.num_processes, n_valid=nvs, init_perm=ips)
-        return p, f
+        return p, f, None
 
     def _dispatch_sharded(self, algorithm: str, sa_cfg, ga_cfg,
                           Cs, Ms, keys, nvs, ips):

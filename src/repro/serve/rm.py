@@ -57,13 +57,13 @@ import heapq
 import json
 import math
 import os
-import time
 from dataclasses import asdict, dataclass
 from typing import (Callable, Dict, List, Optional, Sequence, Tuple,
                     Union)
 
 import numpy as np
 
+from repro.serve import telemetry
 from repro.serve.cluster import Candidate, ClusterState
 from repro.serve.fleet import EngineFleet
 from repro.serve.mapper import (MapRequest, MapResponse, MappingEngine,
@@ -129,7 +129,8 @@ class JobHandle:
 
     ``wait_s`` is queue wait in virtual seconds (start - arrival);
     ``map_wall_s`` is the real wall time the candidate wave spent in the
-    mapping engine (the paper's "reasonable time" budget)."""
+    mapping engine (the paper's "reasonable time" budget): its ``rm.wave``
+    span, first submit to last result."""
 
     __slots__ = ("spec", "C", "seq", "state", "arrival_s", "start_s",
                  "finish_s", "response", "allocation", "candidate_policy",
@@ -618,6 +619,13 @@ class ResourceManager:
         """EASY backfilling at the current clock: start the head while it
         fits; once blocked, compute its shadow (time, spare) and start
         later jobs only if they cannot delay it."""
+        queued = len(self._queue)
+        with telemetry.span("rm.pass", clock=self.clock,
+                            queued=queued) as span:
+            self._easy_pass()
+            span.set(started=queued - len(self._queue))
+
+    def _easy_pass(self) -> None:
         self._sort_queue()
         while self._queue and self._try_start(self._queue[0]):
             self._queue.pop(0)
@@ -657,6 +665,11 @@ class ResourceManager:
         """The allocate-then-map wave: carve K candidates, reserve their
         union, score all K induced subgraphs in one engine wave, promote
         the argmin candidate.  False when the job cannot start now."""
+        with telemetry.span("rm.place", job=h.job_id, size=h.spec.size,
+                            backfilled=backfilled):
+            return self._place(h, backfilled)
+
+    def _place(self, h: JobHandle, backfilled: bool) -> bool:
         spec = h.spec
         cands = self.cluster.candidate_subsets(
             spec.size, k=self.candidates, policies=self.policies)
@@ -670,17 +683,20 @@ class ResourceManager:
             algorithm = spec.algorithm or self.algorithm
             deadline = (spec.deadline_ms if spec.deadline_ms is not None
                         else self.deadline_ms)
-            t0 = time.perf_counter()
-            batches0 = self.engine.stats.solver_batches
-            futs = [self.engine.submit(MapRequest(
-                job_id=f"{spec.job_id}#c{i}", C=h.C, M=cand.M_sub,
-                algorithm=algorithm, seed=spec.seed, deadline_ms=deadline))
-                for i, cand in enumerate(cands)]
-            if not self.engine.running:
-                self.engine.flush()
-            resps = [f.result(self.map_timeout_s) for f in futs]
-            wave_batches = self.engine.stats.solver_batches - batches0
-            h.map_wall_s = time.perf_counter() - t0
+            with telemetry.span("rm.wave", job=spec.job_id,
+                                candidates=len(cands)) as wave:
+                batches0 = self.engine.stats.solver_batches
+                futs = [self.engine.submit(MapRequest(
+                    job_id=f"{spec.job_id}#c{i}", C=h.C, M=cand.M_sub,
+                    algorithm=algorithm, seed=spec.seed,
+                    deadline_ms=deadline))
+                    for i, cand in enumerate(cands)]
+                if not self.engine.running:
+                    self.engine.flush()
+                resps = [f.result(self.map_timeout_s) for f in futs]
+                wave_batches = self.engine.stats.solver_batches - batches0
+                wave.set(batches=wave_batches)
+            h.map_wall_s = wave.dur
             scores = [self.score(r, c, h.C)
                       for r, c in zip(resps, cands)]
             best = int(np.argmin(scores))     # ties -> first policy wins

@@ -10,10 +10,12 @@ solves must be **bitwise identical**: objectives, permutations, and exchange
 histories, for cold, warm-started (``init_perm``), and padded (``n_valid``)
 PSA and PCA solves.
 """
+import functools
 from dataclasses import replace
 
 import numpy as np
 import jax
+import pytest
 import jax.numpy as jnp
 
 from repro.core import annealing, composite, qap
@@ -159,3 +161,67 @@ def test_unknown_loop_rejected():
     cfg = replace(SA_SMALL, loop="nope")
     with pytest.raises(ValueError, match="hot-loop"):
         annealing.run_psa(C, M, jax.random.PRNGKey(0), cfg, num_processes=2)
+
+
+# ------------------------------------------------------- loop counts
+def test_loop_counts_leave_psa_batch_bitwise_unchanged():
+    """counts=True adds the event loop's per-lane, per-level counts and
+    changes nothing else: perms, F and history are bitwise those of the
+    program without them."""
+    sizes = [8, 12, 16, 16]
+    Cs, Ms, nvs, keys = padded_batch(sizes, bucket=16)
+    ips = _warm_rows(sizes, bucket=16)
+    kw = dict(num_processes=2, n_valid=nvs, init_perm=ips)
+    off = annealing.run_psa_batch(Cs, Ms, keys, SA_SMALL, **kw)
+    *on, counts = annealing.run_psa_batch(Cs, Ms, keys, SA_SMALL, counts=True,
+                                          **kw)
+    _assert_bitwise(on, off)
+    levels = SA_SMALL.num_exchanges * SA_SMALL.iters_per_exchange
+    shape = (len(sizes), 2, SA_SMALL.solvers, levels)
+    assert counts.rounds.shape == counts.accepts.shape == shape
+    rounds, accepts = np.asarray(counts.rounds), np.asarray(counts.accepts)
+    assert (rounds >= 1).all() and (accepts >= 0).all()
+    assert (accepts <= np.minimum(rounds, SA_SMALL.max_success)).all()
+
+
+def _level_draws(t, n, cfg):
+    kpair, kacc = jax.random.split(jax.random.PRNGKey(200 + t))
+    return (qap.random_swap_pairs(kpair, cfg.max_neighbors, n, None),
+            jax.random.uniform(kacc, (cfg.max_neighbors,)))
+
+
+@pytest.mark.parametrize("width", [1, 3, None, SA_SMALL.max_neighbors])
+def test_event_loop_accepts_equal_the_scan_oracle(width):
+    """Level by level, on the same draws, the event loop accepts as many
+    moves as the sequential scan, and runs no more rounds than one per
+    acceptance plus one per window of the candidate list."""
+    cfg = replace(SA_SMALL, event_width=width)
+    k, w = cfg.max_neighbors, annealing.resolved_event_width(cfg, 12)
+    bound = min(cfg.max_success, k) + -(-k // w)
+    event = jax.jit(functools.partial(annealing._acceptance_event_loop,
+                                      cfg=cfg, counts=True))
+    scan = jax.jit(functools.partial(annealing._candidate_scan, cfg=SA_SCAN))
+    C, M = map(jnp.asarray, instance(12, 11))
+    state = annealing.init_chain(C, M, jax.random.PRNGKey(3), cfg)
+    t0, seen = state.temp, set()
+    for t in range(16):
+        state = state._replace(temp=t0 * 0.5 ** t)   # hot to frozen
+        pairs, us = _level_draws(t, 12, cfg)
+        *ev, rounds, accepts = event(C, M, state, pairs, us)
+        *sc, scan_accepts = scan(C, M, state, pairs, us)
+        assert int(accepts) == int(scan_accepts), t
+        assert 1 <= int(rounds) <= bound, t
+        for a, b in zip(ev, sc):
+            assert np.asarray(a).tobytes() == np.asarray(b).tobytes(), t
+        seen.add(int(accepts))
+        state = state._replace(p=ev[0], f=ev[1], best_p=ev[2], best_f=ev[3])
+    assert 0 in seen and cfg.max_success in seen    # frozen and capped levels
+
+
+def test_loop_counts_only_from_the_event_loop():
+    C, M = map(jnp.asarray, instance(8, 1))
+    beta = annealing.make_beta(C, M, jax.random.PRNGKey(1), SA_SCAN)
+    s0 = annealing.init_chain(C, M, jax.random.PRNGKey(2), SA_SCAN)
+    with pytest.raises(ValueError, match="no counts"):
+        annealing.temperature_step(C, M, s0, jax.random.PRNGKey(3), SA_SCAN,
+                                   beta, counts=True)
