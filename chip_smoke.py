@@ -66,7 +66,8 @@ import jax.numpy as jnp  # noqa: E402
 from repro.core import (annealing, batch_sharded, composite,  # noqa: E402
                         exact, genetic, instances, sparse)
 from repro.kernels import ops, ref  # noqa: E402
-from repro.kernels.qap_delta import qap_delta_pallas_batch  # noqa: E402
+from repro.kernels.qap_delta import (  # noqa: E402
+    ROW_FORM_MAX_N, qap_delta_pallas_batch, qap_delta_rows_pallas_batch)
 from repro.kernels.qap_objective import (  # noqa: E402
     MAX_KERNEL_N, qap_objective_pallas_batch)
 from repro.kernels.qap_sparse import (  # noqa: E402
@@ -260,6 +261,11 @@ def kernel_phase(dense_orders=(32, 128, MAX_KERNEL_N),
                    - np_objective(C0, M0, ps[0]) for a, b in pr[0]]
         check(np.array_equal(got, want) and np.array_equal(got[0], exact_d),
               f"dense delta n={n}: kernel and reference differ")
+        if n <= ROW_FORM_MAX_N:
+            rows = np.asarray(qap_delta_rows_pallas_batch(
+                Cs, Ms, jnp.asarray(ps), jnp.asarray(pr)))
+            check(rows.tobytes() == got.tobytes(),
+                  f"dense delta n={n}: row and candidate forms differ")
         print(f"  dense n={n}: objective and delta match bitwise", flush=True)
 
     for dims in torus_dims:

@@ -17,6 +17,9 @@ QAP kernel here:
   pass on the MXU.  Every dot here is a one-hot gather, which is exact
   only at ``HIGHEST`` (the f32 operand is split into bf16 parts whose sum
   reproduces it bit for bit), so that precision is pinned.
+  :func:`dot_onehot` / :func:`onehot_dot` make that split themselves:
+  three single-pass dots against the bf16-exact one-hot, the same bits
+  in about half the MXU passes, for kernels the MXU sets the pace of.
 
 Scalar-prefetch tables live in SMEM (1 MiB on v5e); callers chunk their
 grids so a table never exceeds :data:`MAX_PREFETCH_WORDS`.
@@ -91,6 +94,44 @@ def dot(x: Array, y: Array) -> Array:
     return jax.lax.dot_general(x, y, (((1,), (0,)), ((), ())),
                                precision=HIGHEST,
                                preferred_element_type=jnp.float32)
+
+
+def _bf16_parts(x: Array):
+    """Three bfloat16 values whose float32 sum is ``x`` exactly: each
+    takes the next 8 bits of the 24-bit significand."""
+    parts = []
+    for _ in range(3):
+        part = x.astype(jnp.bfloat16)
+        parts.append(part)
+        x = x - part.astype(jnp.float32)
+    return parts
+
+
+def _single_pass_sum(pairs) -> Array:
+    """Sum, in float32, of single-pass bfloat16 dots of ``(x, y)`` pairs."""
+    out = None
+    for x, y in pairs:
+        d = jax.lax.dot_general(x, y, (((1,), (0,)), ((), ())),
+                                preferred_element_type=jnp.float32)
+        out = d if out is None else out + d
+    return out
+
+
+def dot_onehot(x: Array, onehot_cols: Array) -> Array:
+    """``x @ onehot_cols`` for a 0/1 one-hot right operand, with the same
+    bits as :func:`dot` at half its MXU passes: ``x`` is split into three
+    exact bfloat16 parts, each takes one single-pass dot (every output is
+    one product, exact in float32), and the three are summed in float32,
+    which rebuilds each gathered value exactly."""
+    oh = onehot_cols.astype(jnp.bfloat16)
+    return _single_pass_sum((part, oh) for part in _bf16_parts(x))
+
+
+def onehot_dot(onehot_rows: Array, x: Array) -> Array:
+    """``onehot_rows @ x`` for a 0/1 one-hot left operand; as
+    :func:`dot_onehot`."""
+    oh = onehot_rows.astype(jnp.bfloat16)
+    return _single_pass_sum((oh, part) for part in _bf16_parts(x))
 
 
 def stack_rows(*rows: Array) -> Array:

@@ -40,7 +40,8 @@ import jax.numpy as jnp
 
 from ..core.sparse import SparseFlows
 from . import ref
-from .qap_delta import qap_delta_pallas_batch
+from .qap_delta import (ROW_FORM_MAX_N, qap_delta_pallas_batch,
+                        qap_delta_rows_pallas_batch)
 from .qap_ga_step import qap_ga_step_pallas_batch
 from .mosaic import padded_order
 from .qap_objective import qap_objective_pallas_batch, MAX_KERNEL_N
@@ -162,16 +163,21 @@ def qap_objective(C: Array, M: Array, perms: Array, *,
 
 # -------------------------------------------------------------------- delta
 
+_DELTA_KERNELS = {"row": qap_delta_rows_pallas_batch,
+                  "candidate": qap_delta_pallas_batch}
+
+
 @functools.lru_cache(maxsize=None)
-def _delta_shared(interpret: bool):
+def _delta_shared(interpret: bool, form: str):
     """Kernel dispatch for shared matrices; (..., N) x (..., K, 2) -> (..., K)."""
+    kernel = _DELTA_KERNELS[form]
+
     @jax.custom_batching.custom_vmap
     def delta(C, M, p, pairs):
         n, k = p.shape[-1], pairs.shape[-2]
         lead = p.shape[:-1]
-        out = qap_delta_pallas_batch(
-            C, M, p.reshape((-1, n)), pairs.reshape((-1, k, 2)),
-            interpret=interpret)
+        out = kernel(C, M, p.reshape((-1, n)), pairs.reshape((-1, k, 2)),
+                     interpret=interpret)
         return out.reshape(lead + (k,))
 
     @delta.def_vmap
@@ -181,22 +187,23 @@ def _delta_shared(interpret: bool):
         pairs = _bcast(pairs, rb, axis_size)
         if not (cb or mb):
             return delta(C, M, p, pairs), True
-        return _delta_inst(interpret)(
+        return _delta_inst(interpret, form)(
             _bcast(C, cb, axis_size), _bcast(M, mb, axis_size), p, pairs), True
 
     return delta
 
 
 @functools.lru_cache(maxsize=None)
-def _delta_inst(interpret: bool):
+def _delta_inst(interpret: bool, form: str):
     """Instance-batched form: C, M (B, N, N); p (B, ..., N) -> (B, ..., K)."""
+    kernel = _DELTA_KERNELS[form]
+
     @jax.custom_batching.custom_vmap
     def delta_i(Cs, Ms, p, pairs):
         n, k = p.shape[-1], pairs.shape[-2]
         lead = p.shape[:-1]
-        out = qap_delta_pallas_batch(
-            Cs, Ms, p.reshape((-1, n)), pairs.reshape((-1, k, 2)),
-            interpret=interpret)
+        out = kernel(Cs, Ms, p.reshape((-1, n)), pairs.reshape((-1, k, 2)),
+                     interpret=interpret)
         return out.reshape(lead + (k,))
 
     @delta_i.def_vmap
@@ -225,9 +232,10 @@ def qap_delta(C: Array, M: Array, p: Array, pairs: Array, *,
     SA hot loop's wide evaluation surface (``annealing.temperature_step``
     scores all remaining candidates of a temperature level in one call):
     on CPU it runs the vectorized reference (bitwise-equal per candidate
-    to ``core.qap.swap_delta``), on TPU the Pallas kernel — a single
-    launch whose grid spans every (leading-dim, candidate) pair, with
-    outer vmaps (chains, solvers, instances) folded into the grid.
+    to ``core.qap.swap_delta``), on TPU one Pallas launch of the form
+    :func:`delta_form` picks, with outer vmaps (chains, solvers,
+    instances) folded into its grid.  ``force_pallas`` runs the kernel
+    form the order would take on TPU.
 
     A ``SparseFlows`` ``C`` routes to :func:`qap_delta_sparse`, so the
     solvers' call sites are representation-agnostic.
@@ -235,18 +243,37 @@ def qap_delta(C: Array, M: Array, p: Array, pairs: Array, *,
     if isinstance(C, SparseFlows):
         return qap_delta_sparse(C, M, p, pairs, force_pallas=force_pallas,
                                 interpret=interpret)
-    fits = padded_order(p.shape[-1]) <= MAX_KERNEL_N
-    if force_pallas or (_on_tpu() and fits):
-        return _delta_shared(bool(interpret or not _on_tpu()))(C, M, p, pairs)
-    return ref.qap_delta_ref(C, M, p, pairs)
+    n = p.shape[-1]
+    form = _kernel_form(n) if force_pallas else delta_form(n)
+    if form == "reference":
+        return ref.qap_delta_ref(C, M, p, pairs)
+    return _delta_shared(bool(interpret or not _on_tpu()), form)(
+        C, M, p, pairs)
+
+
+def _kernel_form(n: int) -> str:
+    """The delta kernel's form at order ``n``: the row form up to its
+    VMEM cap, the per-candidate form above it."""
+    return "row" if padded_order(n) <= ROW_FORM_MAX_N else "candidate"
+
+
+def delta_form(n: int) -> str:
+    """What :func:`qap_delta` runs for dense order-``n`` instances:
+    ``"row"`` (one grid step per permutation row, the matrices resident
+    in VMEM) where the padded order fits ``ROW_FORM_MAX_N``,
+    ``"candidate"`` (one grid step per candidate, rows streamed) up to
+    ``MAX_KERNEL_N``, and ``"reference"`` (the jnp formula) above that
+    or off TPU."""
+    if not _on_tpu() or padded_order(n) > MAX_KERNEL_N:
+        return "reference"
+    return _kernel_form(n)
 
 
 def delta_order(n: int) -> int:
     """The order :func:`qap_delta` works at for dense order-``n``
-    instances: the kernel's lane-padded order where it takes the kernel
-    path, ``n`` on the reference path."""
-    n_pad = padded_order(n)
-    return n_pad if _on_tpu() and n_pad <= MAX_KERNEL_N else n
+    instances: the kernel's lane-padded order where it takes a kernel
+    form, ``n`` on the reference path."""
+    return n if delta_form(n) == "reference" else padded_order(n)
 
 
 # --------------------------------------------------------- fused solver steps

@@ -63,11 +63,11 @@ def _objective_kernel(p_ref, c_ref, m_ref, out_ref, *, n_pad: int):
 def matrix_spec(n_pad: int, batched: bool, instance_of):
     """BlockSpec of a whole (n_pad, n_pad) matrix per program: shared
     matrices are fetched once and single-buffered; instance-batched ones
-    follow ``instance_of(program) -> instance``."""
+    follow ``instance_of(*grid_indices) -> instance``."""
     if batched:
         return pl.BlockSpec((1, n_pad, n_pad),
-                            lambda i: (instance_of(i), 0, 0))
-    return pl.BlockSpec((n_pad, n_pad), lambda i: (0, 0),
+                            lambda *g: (instance_of(*g), 0, 0))
+    return pl.BlockSpec((n_pad, n_pad), lambda *g: (0, 0),
                         pipeline_mode=pl.Buffered(1))
 
 

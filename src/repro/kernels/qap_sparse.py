@@ -44,7 +44,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from . import mosaic
-from .qap_delta import candidate_table, row_spec
+from .qap_delta import candidate_table, row_spec, rows_per_instance
 
 Array = jax.Array
 
@@ -228,10 +228,7 @@ def qap_delta_sparse_pallas_batch(S, M: Array, ps: Array, pairs: Array,
     n = ps.shape[-1]
     bsz, k = pairs.shape[0], pairs.shape[1]
     mat_batched = M.ndim == 3
-    if mat_batched and (bsz % M.shape[0] != 0):
-        raise ValueError(
-            f"batched S/M leading dim {M.shape[0]} must divide B={bsz}")
-    rpt = (bsz // M.shape[0]) if mat_batched else 1
+    rpt = rows_per_instance(M, bsz)
     n_pad = mosaic.padded_order(n)
     rows = mosaic.pad_to(n, mosaic.SUBLANE)
     d_pad = mosaic.pad_to(max(S.cols.shape[-1], mosaic.LANE), mosaic.LANE)

@@ -1089,7 +1089,9 @@ class MappingEngine:
             ips = self._init_perm_batch(reqs, bucket, warms, Bp)
             ips_j = None if ips is None else jnp.asarray(ips)
         with telemetry.span("engine.dispatch", algorithm=algorithm, tier=tier,
-                            path="bucket") as dispatch:
+                            path="bucket",
+                            delta_form=kernel_ops.delta_form(bucket)
+                            ) as dispatch:
             perms, fs, counts = self._dispatch(
                 algorithm, tier, Cs_j, Ms_j, jnp.stack(keys), nvs_j, ips_j)
             if self.polish_rounds > 0:

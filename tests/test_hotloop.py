@@ -18,7 +18,8 @@ import jax
 import pytest
 import jax.numpy as jnp
 
-from repro.core import annealing, composite, qap
+from repro.core import annealing, composite, mapping, qap
+from repro.kernels import ops
 
 from _fixtures import SA_SMALL, PCA_SMALL, instance, padded_batch
 
@@ -225,3 +226,43 @@ def test_loop_counts_only_from_the_event_loop():
     with pytest.raises(ValueError, match="no counts"):
         annealing.temperature_step(C, M, s0, jax.random.PRNGKey(3), SA_SCAN,
                                    beta, counts=True)
+
+
+# ------------------------------------------------------ delta kernel forms
+def _kernel_wave(Cs, Ms, keys, nvs, ips):
+    """One bucket PSA wave with loop counts, then the engine's polish."""
+    perms, fs, _, counts = annealing.run_psa_batch(
+        Cs, Ms, keys, SA_SMALL, num_processes=2, n_valid=nvs, init_perm=ips,
+        counts=True)
+    pkeys = jnp.stack([jax.random.fold_in(k, 7) for k in keys])
+    pp, pf = mapping.polish_batch(Cs, Ms, perms, pkeys, 4, nvs)
+    return perms, fs, counts, pp, pf
+
+
+def test_delta_kernel_forms_give_the_same_wave(monkeypatch):
+    """A bucket PSA wave and its polish, with every dense delta run by the
+    row-form kernel and then by the per-candidate form (interpret mode),
+    give bitwise-equal perms, F and loop counts, and equal those of the
+    reference path: the same accept decisions, candidate by candidate."""
+    sizes = [8, 12, 16, 16]
+    Cs, Ms, nvs, keys = padded_batch(sizes, bucket=16)
+    ips = _warm_rows(sizes, bucket=16)
+    forms = {"reference": jax.tree_util.tree_leaves(
+        _kernel_wave(Cs, Ms, keys, nvs, ips))}
+    kernel_delta = functools.partial(ops.qap_delta, force_pallas=True,
+                                     interpret=True)
+    monkeypatch.setattr(ops, "qap_delta", kernel_delta)
+    # Trace caches key on signatures only: the kernel-path traces must
+    # neither come from nor leak into the reference-path tests.
+    jax.clear_caches()
+    try:
+        for form in ("row", "candidate"):
+            monkeypatch.setattr(ops, "_kernel_form", lambda n, f=form: f)
+            forms[form] = jax.tree_util.tree_leaves(
+                _kernel_wave(Cs, Ms, keys, nvs, ips))
+            jax.clear_caches()
+    finally:
+        jax.clear_caches()
+    for form in ("row", "candidate"):
+        for got, want in zip(forms[form], forms["reference"]):
+            assert np.asarray(got).tobytes() == np.asarray(want).tobytes()

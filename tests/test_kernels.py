@@ -8,10 +8,12 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.kernels import ref, ops
-from repro.kernels.qap_objective import (qap_objective_pallas,
+from repro.kernels import mosaic, ref, ops
+from repro.kernels.qap_objective import (MAX_KERNEL_N, qap_objective_pallas,
                                          qap_objective_pallas_batch)
-from repro.kernels.qap_delta import qap_delta_pallas, qap_delta_pallas_batch
+from repro.kernels.qap_delta import (ROW_FORM_MAX_N, qap_delta_pallas,
+                                     qap_delta_pallas_batch,
+                                     qap_delta_rows_pallas_batch)
 from repro.kernels.qap_sparse import (qap_delta_sparse_pallas_batch,
                                       qap_objective_sparse_pallas_batch)
 from repro.core import qap, sparse
@@ -225,6 +227,98 @@ def test_ops_delta_under_vmap_matches_flat_dispatch():
     flat = jax.jit(lambda: ops.qap_delta(C, M, ps, pairs))
     assert np.asarray(per_chain(ps, pairs)).tobytes() == \
         np.asarray(flat()).tobytes()
+
+
+def _padded_rows(rng, n, sizes, rpt, k):
+    """Instances of the given valid sizes zero-padded to order n (one per
+    size), rpt permutations each with identity pad tails, and k candidate
+    swaps per permutation inside its valid prefix."""
+    Cs, Ms = np.zeros((2, len(sizes), n, n), np.float32)
+    ps = np.tile(np.arange(n, dtype=np.int32), (len(sizes) * rpt, 1))
+    pairs = np.zeros((len(sizes) * rpt, k, 2), np.int32)
+    for r, nv in enumerate(sizes):
+        C, M = _instance(rng, nv, np.float32)
+        Cs[r, :nv, :nv], Ms[r, :nv, :nv] = C, M
+        for i in range(r * rpt, (r + 1) * rpt):
+            ps[i, :nv] = rng.permutation(nv)
+            pairs[i] = [rng.choice(nv, 2, replace=False) for _ in range(k)]
+    return (jnp.asarray(Cs), jnp.asarray(Ms), jnp.asarray(ps),
+            jnp.asarray(pairs))
+
+
+# (n, k, valid sizes, rows per instance, batched C/M): n_pad 128 and 256,
+# one to 256 candidates (256 fills a row-form block), padded instances.
+@pytest.mark.parametrize("n,k,sizes,rpt,batched", [
+    (5, 3, (5,), 3, False),
+    (5, 25, (5, 4), 2, True),
+    (32, 1, (32, 20), 3, True),
+    (32, 256, (32,), 2, False),
+    (100, 25, (100, 61, 2), 2, True),
+    (128, 3, (128,), 4, False),
+    (128, 25, (128, 100, 60, 17), 2, True),
+    (128, 256, (128, 90), 1, True),
+    (200, 1, (200,), 2, False),
+    (200, 25, (200, 150), 2, True),
+    (200, 256, (199,), 2, False),
+])
+def test_delta_row_form_bitwise(n, k, sizes, rpt, batched):
+    """The row form of the dense delta kernel equals the reference and
+    the per-candidate form bit for bit on integer instances, with shared
+    or instance-batched matrices and identity pad tails."""
+    rng = np.random.default_rng(n * 31 + k)
+    Cs, Ms, ps, pairs = _padded_rows(rng, n, sizes, rpt, k)
+    if batched:
+        want = jnp.concatenate([
+            ref.qap_delta_ref(Cs[r], Ms[r], ps[r * rpt:(r + 1) * rpt],
+                              pairs[r * rpt:(r + 1) * rpt])
+            for r in range(len(sizes))])
+    else:
+        Cs, Ms = Cs[0], Ms[0]
+        want = ref.qap_delta_ref(Cs, Ms, ps, pairs)
+    rows = qap_delta_rows_pallas_batch(Cs, Ms, ps, pairs, interpret=True)
+    cands = qap_delta_pallas_batch(Cs, Ms, ps, pairs, interpret=True)
+    assert rows.shape == (len(ps), k)
+    assert np.asarray(rows).tobytes() == np.asarray(want).tobytes()
+    assert np.asarray(rows).tobytes() == np.asarray(cands).tobytes()
+
+
+@pytest.mark.parametrize("scale", [1.0, 1e-20, 1e20])
+def test_split_onehot_dots_gather_exactly(scale):
+    """The three-part bfloat16 one-hot dots rebuild every gathered float32
+    value exactly, whatever its significand and exponent."""
+    rng = np.random.default_rng(7)
+    x = jnp.asarray(rng.standard_normal((128, 128)) * scale, jnp.float32)
+    idx = rng.integers(0, 128, 128).astype(np.int32)
+    oh = mosaic.onehot(jnp.asarray(idx)[None], 128)      # oh[j, k] = idx_k == j
+    np.testing.assert_array_equal(np.asarray(mosaic.dot_onehot(x, oh)),
+                                  np.asarray(x)[:, idx])
+    np.testing.assert_array_equal(np.asarray(mosaic.onehot_dot(oh.T, x)),
+                                  np.asarray(x)[idx, :])
+
+
+@pytest.mark.parametrize("n,form", [(5, "row"), (ROW_FORM_MAX_N, "row"),
+                                    (ROW_FORM_MAX_N + 1, "candidate"),
+                                    (MAX_KERNEL_N, "candidate"),
+                                    (MAX_KERNEL_N + 1, "reference")])
+def test_delta_form_by_padded_order(monkeypatch, n, form):
+    """On TPU the padded order alone picks the delta kernel's form (the
+    row form up to its VMEM cap); off TPU the reference runs.  The forced
+    kernel path takes the same form and equals the reference bitwise at
+    the cap and just above it."""
+    assert ops.delta_form(n) == "reference"
+    assert ops.delta_order(n) == n
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    assert ops.delta_form(n) == form
+    assert ops.delta_order(n) == (n if form == "reference"
+                                  else mosaic.padded_order(n))
+    monkeypatch.undo()
+    if n in (ROW_FORM_MAX_N, ROW_FORM_MAX_N + 1):
+        rng = np.random.default_rng(n)
+        C, M, ps, pairs = _padded_rows(rng, n, (n,), 2, 3)
+        got = ops.qap_delta(C[0], M[0], ps, pairs, force_pallas=True,
+                            interpret=True)
+        want = ref.qap_delta_ref(C[0], M[0], ps, pairs)
+        assert np.asarray(got).tobytes() == np.asarray(want).tobytes()
 
 
 def test_ops_objective_leading_batch_dispatch():

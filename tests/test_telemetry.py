@@ -140,6 +140,21 @@ def test_fetch_counts_bound_the_batched_loop():
                if not h.response.cached)
 
 
+def test_bucket_dispatch_names_the_delta_form(monkeypatch):
+    """Every bucket ``engine.dispatch`` span carries the dense delta form
+    ``kernel_ops.delta_form`` picks for its bucket: the reference off TPU,
+    and on TPU the row form at the engine's buckets."""
+    from repro.kernels import ops
+    t0 = time.perf_counter()
+    _replay()
+    ring = telemetry.spans(t0, time.perf_counter(), ["engine.dispatch"])
+    buckets = [r for r in ring if r.attrs.get("path") == "bucket"]
+    assert buckets and all(r.attrs["delta_form"] == "reference"
+                           for r in buckets)
+    monkeypatch.setattr(ops, "_on_tpu", lambda: True)
+    assert {ops.delta_form(b) for b in MappingEngine().buckets} == {"row"}
+
+
 def test_response_seconds_are_the_groups_dispatch_and_fetch():
     from _fixtures import instance
     from repro.serve import MapRequest
@@ -153,5 +168,5 @@ def test_response_seconds_are_the_groups_dispatch_and_fetch():
     d, f = (next(r for r in ring if r.name == name)
             for name in ("engine.dispatch", "engine.fetch"))
     assert d.attrs == {"algorithm": "psa", "tier": "default",
-                       "path": "bucket"}
+                       "path": "bucket", "delta_form": "reference"}
     assert {r.seconds for r in out.values()} == {(0.0 + d.dur + f.dur) / 3}

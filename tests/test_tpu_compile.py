@@ -19,7 +19,8 @@ from jax.sharding import (Mesh, NamedSharding, PartitionSpec as P,
 from repro.core import (annealing, batch_sharded, composite, genetic,
                         mapping, sparse)
 from repro.kernels import ops
-from repro.kernels.qap_delta import qap_delta_pallas_batch
+from repro.kernels.qap_delta import (ROW_FORM_MAX_N, qap_delta_pallas_batch,
+                                     qap_delta_rows_pallas_batch)
 from repro.kernels.qap_objective import (MAX_KERNEL_N,
                                          qap_objective_pallas_batch)
 from repro.kernels.qap_sparse import (MAX_SPARSE_KERNEL_N,
@@ -87,6 +88,20 @@ def test_delta_kernel_compiles(one_chip, n, lead):
     _compile_has_kernel(qap_delta_pallas_batch, s(*lead, n, n),
                         s(*lead, n, n), _spec(one_chip, (64, n), jnp.int32),
                         _spec(one_chip, (64, 25, 2), jnp.int32))
+
+
+# The row form at bucket 128 and at its cap, at the SA event loop's shapes
+# (64 rows, 25 candidates) and the polish's (4 rows, 256 candidates: one
+# full block), with shared and instance-batched matrices.
+@pytest.mark.parametrize("n", [128, ROW_FORM_MAX_N])
+@pytest.mark.parametrize("lead,rows,k", [((), 64, 25), ((4,), 64, 25),
+                                         ((4,), 4, 256)])
+def test_delta_row_form_compiles(one_chip, n, lead, rows, k):
+    s = lambda *shape: _spec(one_chip, shape)
+    text = jax.jit(qap_delta_rows_pallas_batch).lower(
+        s(*lead, n, n), s(*lead, n, n), _spec(one_chip, (rows, n), jnp.int32),
+        _spec(one_chip, (rows, k, 2), jnp.int32)).compile().as_text()
+    assert "tpu_custom_call" in text and "qap_delta" in text
 
 
 # Sparse kernels at bucket 128 and at their cap; d=200 spans two
