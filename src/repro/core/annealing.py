@@ -46,10 +46,11 @@ from __future__ import annotations
 import functools
 import time
 from dataclasses import dataclass
-from typing import NamedTuple, Optional, Tuple, Union
+from typing import NamedTuple, Optional, Union
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.kernels import ops as kernel_ops
 from repro.kernels import prng
@@ -97,7 +98,7 @@ class SAConfig:
     flows: str = "dense"             # "dense" | "sparse" flow representation:
                                      # "sparse" expects C as a
                                      # core.sparse.SparseFlows (convert once,
-                                     # host-side, via sparse.prepare_flows) and
+                                     # host-side, via sparse.from_dense) and
                                      # runs the O(nnz) delta/objective
                                      # dispatches — bitwise-equal to dense on
                                      # the integer instance families
@@ -110,6 +111,17 @@ class LoopCounts(NamedTuple):
     one loop, which runs each level until its slowest lane is done."""
     rounds: Array   # event-loop rounds the lane ran        (..., levels)
     accepts: Array  # moves the lane accepted               (..., levels)
+
+
+def loop_totals(counts: LoopCounts) -> dict:
+    """A solve's loop counts, for its ``engine.fetch`` span: the rounds
+    the (batched) event loop ran (at each level, as many as its slowest
+    lane), the rounds its lanes ran, the lanes and the accepted moves."""
+    rounds = np.asarray(counts.rounds)
+    levels = rounds.reshape(-1, rounds.shape[-1])
+    return {"rounds_executed": int(levels.max(axis=0).sum()),
+            "lane_rounds": int(rounds.sum()), "lanes": levels.shape[0],
+            "accepts": int(np.asarray(counts.accepts).sum())}
 
 
 class SAState(NamedTuple):
@@ -496,7 +508,7 @@ def _psa_impl(C: Array, M: Array, key: Array, cfg: SAConfig,
     if cfg.flows == "sparse" and not isinstance(C, sparse.SparseFlows):
         raise TypeError(
             "SAConfig.flows='sparse' requires C as a core.sparse.SparseFlows"
-            " — convert host-side with sparse.prepare_flows(C, 'sparse')")
+            " — convert host-side with sparse.from_dense(C)")
     if n_valid is not None:
         C = qap.mask_flows(C, n_valid)
     kinit, kbeta, krun = jax.random.split(key, 3)
@@ -553,20 +565,23 @@ def _psa_impl(C: Array, M: Array, key: Array, cfg: SAConfig,
     return best_p, flat_f[i], history, LoopCounts(*map(per_lane, tally))
 
 
-@functools.partial(jax.jit, static_argnames=("cfg", "num_processes", "exchange"))
+@functools.partial(jax.jit, static_argnames=("cfg", "num_processes", "exchange",
+                                             "counts"))
 def run_psa(C: Array, M: Array, key: Array, cfg: SAConfig,
             num_processes: int = 4, exchange: bool = True,
             n_valid: Optional[Array] = None,
-            init_perm: Optional[Array] = None) -> Tuple[Array, Array, Array]:
+            init_perm: Optional[Array] = None, counts: bool = False):
     """Parallel SA on a (num_processes, solvers) chain grid (single host).
 
     Returns (best_perm, best_f, history) where history[r] is the global best
     objective after exchange round r.  ``n_valid`` restricts the search to a
     padded instance's valid prefix (see ``_psa_impl``); ``init_perm``
-    warm-starts chain 0 of every process from a given permutation.
+    warm-starts chain 0 of every process from a given permutation.  With
+    ``counts`` (the event loop only) a fourth result holds the
+    :class:`LoopCounts`; the first three are bitwise those without.
     """
     return _psa_impl(C, M, key, cfg, num_processes, exchange, n_valid,
-                     init_perm)
+                     init_perm, counts)
 
 
 @functools.partial(jax.jit, static_argnames=("cfg", "num_processes", "exchange",

@@ -82,7 +82,7 @@ class GAConfig:
     flows: str = "dense"         # "dense" | "sparse" flow representation:
                                  # "sparse" expects C as a
                                  # core.sparse.SparseFlows (convert host-side
-                                 # via sparse.prepare_flows); the wide
+                                 # via sparse.from_dense); the wide
                                  # generation's objective dispatch then runs
                                  # O(nnz) per offspring (docs/DESIGN.md §10)
 
@@ -584,7 +584,7 @@ def _pga_impl(C: Array, M: Array, key: Array, cfg: GAConfig,
     if cfg.flows == "sparse" and not isinstance(C, sparse.SparseFlows):
         raise TypeError(
             "GAConfig.flows='sparse' requires C as a core.sparse.SparseFlows"
-            " — convert host-side with sparse.prepare_flows(C, 'sparse')")
+            " — convert host-side with sparse.from_dense(C)")
     if n_valid is not None:
         C = qap.mask_flows(C, n_valid)
     n = C.shape[0]

@@ -64,6 +64,12 @@ def polish(C: Array, M: Array, p: Array, key: Array, rounds: int = 200,
     return p, f
 
 
+# ``polish`` as one program per shape: callers that polish one instance
+# at a time (the multilevel pipeline) reuse it instead of tracing the
+# scan again on every call.
+polish_jit = jax.jit(polish, static_argnames=("rounds",))
+
+
 @functools.partial(jax.jit, static_argnames=("rounds",))
 def polish_batch(Cs: Array, Ms: Array, ps: Array, keys: Array,
                  rounds: int = 200, n_valid: Optional[Array] = None) -> tuple:

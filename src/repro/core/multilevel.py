@@ -14,10 +14,19 @@ pipeline over the repo's existing machinery (docs/DESIGN.md §10):
   closest-pair contraction of the system graph, with the coarse distance
   between clusters the minimum member distance.  Matchings are perfect
   (every cluster has exactly 2 members; levels halve), so prolongation is
-  a permutation by construction; an odd order just stops coarsening early.
+  a permutation by construction.  An odd order first pins its lightest
+  process on its most remote node (``match_level``): the pair stays out
+  of the coarser levels and is placed together on prolongation, and the
+  rest halves.  Every order coarsens down to ``coarse_n``.
 * **Coarse solve**: the existing batched solvers (``run_psa``/``run_pga``)
   on the dense coarse instance — at ``coarse_n`` the dense path is the
   fast one.
+* **Shapes**: every device program takes its shapes from the order
+  alone.  A level of order n is solved at the next power of two
+  (``level_order``), padded with zero flows and masked by ``n_valid``;
+  its ELL width comes from that order and the flows' density class
+  (``sparse.ell_width``), never from the densest row.  So one deployment
+  meets a short, fixed list of programs (docs/DESIGN.md §10).
 * **Refinement**: prolong one level and warm-start SA via the solvers'
   ``init_perm`` argument.  Chain 0 of every process starts from the
   prolonged permutation, so the refined objective can never end above it
@@ -27,19 +36,25 @@ pipeline over the repo's existing machinery (docs/DESIGN.md §10):
   **sparse** representation (``SAConfig.flows="sparse"``) — O(nnz) per
   candidate — which is what keeps n=4096 interactive.
 * **Final polish**: the finest level ends with the batched 2-swap descent
-  (``mapping.polish``), also through the sparse dispatches.
+  (``mapping.polish_jit``), also through the sparse dispatches.
+
+``span`` (``solve_multilevel``'s hook, ``serve.telemetry.span`` in the
+engine) times the host coarsening (``ml.coarsen``), each refinement level
+(``ml.level``) and every wait for the device's results (``engine.fetch``,
+with the event loop's counts).
 """
 from __future__ import annotations
 
+import contextlib
 import time
 from dataclasses import dataclass, field
-from typing import NamedTuple, Optional, Tuple
+from typing import Callable, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
-from . import annealing, genetic, mapping, qap, sparse
+from . import annealing, genetic, mapping, sparse
 
 Array = jax.Array
 
@@ -137,20 +152,44 @@ def closest_pair_matching(M: np.ndarray) -> np.ndarray:
     return np.asarray(pairs, dtype=np.int64)
 
 
+def match_level(C: np.ndarray, M: np.ndarray):
+    """One level's matchings at any order: (flow_pairs, sys_pairs, pinned).
+
+    An even order is matched whole and ``pinned`` is None.  An odd order
+    first sets aside its lightest process (least total flow) and its most
+    remote node (largest distance sum): ``pinned = (process, node)``, the
+    process placed on the node when the level is prolonged, and both left
+    out of the coarser levels; the rest is matched as an even order.
+    """
+    n = C.shape[0]
+    if n % 2 == 0:
+        return heavy_edge_matching(C), closest_pair_matching(M), None
+    W = C.astype(np.float64)
+    u = int(np.argmin(W.sum(axis=0) + W.sum(axis=1)))
+    w = int(np.argmax(M.astype(np.float64).sum(axis=1)))
+    procs = np.delete(np.arange(n), u)
+    nodes = np.delete(np.arange(n), w)
+    return (procs[heavy_edge_matching(C[np.ix_(procs, procs)])],
+            nodes[closest_pair_matching(M[np.ix_(nodes, nodes)])], (u, w))
+
+
 def coarsen(C: np.ndarray, M: np.ndarray, flow_pairs: np.ndarray,
             sys_pairs: np.ndarray) -> Tuple[np.ndarray, np.ndarray]:
     """Contract one level: flows sum over cluster pairs (intra-cluster
     flows vanish into the diagonal, which is zeroed — they cost the same
     under every coarse assignment up to the member distance the refinement
     level re-exposes); distances take the minimum member distance, an
-    optimistic (admissible) coarse proxy.
+    optimistic (admissible) coarse proxy.  A process in no pair (an odd
+    level's pinned one) drops out with its flows.
     """
     n = C.shape[0]
     nc = flow_pairs.shape[0]
-    cid = np.empty(n, dtype=np.int64)
+    cid = np.full(n, -1, dtype=np.int64)
     cid[flow_pairs[:, 0]] = np.arange(nc)
     cid[flow_pairs[:, 1]] = np.arange(nc)
     ii, jj = np.nonzero(C)
+    kept = (cid[ii] >= 0) & (cid[jj] >= 0)
+    ii, jj = ii[kept], jj[kept]
     Cc = np.zeros((nc, nc), dtype=np.float64)
     np.add.at(Cc, (cid[ii], cid[jj]), C[ii, jj].astype(np.float64))
     np.fill_diagonal(Cc, 0.0)
@@ -164,26 +203,83 @@ def coarsen(C: np.ndarray, M: np.ndarray, flow_pairs: np.ndarray,
 
 
 def prolong_perm(pc: np.ndarray, flow_pairs: np.ndarray,
-                 sys_pairs: np.ndarray) -> np.ndarray:
+                 sys_pairs: np.ndarray,
+                 pinned: Optional[Tuple[int, int]] = None) -> np.ndarray:
     """Lift a coarse assignment: both members of flow cluster c land on
     the two system nodes of its assigned system cluster ``pc[c]`` (the
-    orientation is arbitrary — refinement decides it).  A permutation by
-    construction: both matchings are perfect partitions.
+    orientation is arbitrary — refinement decides it), and an odd level's
+    ``pinned`` process on its node.  A permutation by construction: the
+    matchings and the pinned pair partition both sides.
     """
-    n = 2 * pc.shape[0]
+    n = 2 * pc.shape[0] + (pinned is not None)
     p = np.empty(n, dtype=np.int32)
     p[flow_pairs[:, 0]] = sys_pairs[pc, 0]
     p[flow_pairs[:, 1]] = sys_pairs[pc, 1]
+    if pinned is not None:
+        p[pinned[0]] = pinned[1]
+    return p
+
+
+def level_order(n: int) -> int:
+    """The order a level of n processes is solved at on the device: the
+    next power of two, so that every order shares a few programs."""
+    return 1 << max(n - 1, 0).bit_length()
+
+
+def _padded(A: np.ndarray, order: int) -> np.ndarray:
+    """``A`` with zero rows and columns up to ``order`` (inert: zero flows
+    and distances, masked by ``n_valid``)."""
+    pad = order - A.shape[0]
+    return np.pad(A, ((0, pad), (0, pad))) if pad else A
+
+
+def _padded_perm(p: np.ndarray, order: int) -> np.ndarray:
+    """A level's permutation with the identity on the padded tail."""
+    return np.concatenate([p, np.arange(p.shape[0], order)]).astype(np.int32)
+
+
+def _level_flows(C: np.ndarray, cfg: "MultilevelConfig"):
+    """A refinement level's flows at its device order: dense, or ELL with
+    the width of that order and the flows' density class."""
+    order = level_order(C.shape[0])
+    if cfg.refine_sa.flows != "sparse":
+        return jnp.asarray(_padded(C, order))
+    return sparse.from_dense(_padded(C, order),
+                             width=sparse.ell_width(C, order))
+
+
+@contextlib.contextmanager
+def _no_span(name: str, **attrs):
+    yield _NoSpan()
+
+
+class _NoSpan:
+    def set(self, **attrs) -> None:
+        pass
+
+
+def _fetch(out, n: int, span: Callable):
+    """Wait for a solve's permutation (first result), its order-``n``
+    prefix, inside an ``engine.fetch`` span that carries the event loop's
+    counts when the solve kept them (a fourth result)."""
+    with span("engine.fetch") as fetch:
+        p = np.asarray(out[0])[:n]
+        if len(out) > 3:
+            fetch.set(**annealing.loop_totals(out[3]))
     return p
 
 
 def solve_multilevel(C, M, key: Optional[Array] = None,
-                     cfg: Optional[MultilevelConfig] = None
-                     ) -> MultilevelResult:
+                     cfg: Optional[MultilevelConfig] = None,
+                     span: Callable = _no_span) -> MultilevelResult:
     """Coarsen → solve coarse → prolong-and-refine each level (module
     docstring).  ``C``/``M`` are dense host arrays; coarsening is host-side
     numpy, every solve/refine runs through the jitted solver entry points
-    (sparse dispatches on the refinement levels).
+    (sparse dispatches on the refinement levels), each level at
+    ``level_order`` of its order.  The result is a permutation of the n
+    processes onto the n nodes, never worse than the identity, with F in
+    float64.  ``span(name, **attrs)`` is a context manager for the
+    program's spans (module docstring).
     """
     cfg = cfg or MultilevelConfig()
     if cfg.algorithm not in ("psa", "pga"):
@@ -191,53 +287,74 @@ def solve_multilevel(C, M, key: Optional[Array] = None,
     key = key if key is not None else jax.random.PRNGKey(0)
     C = np.asarray(C, np.float32)
     M = np.asarray(M, np.float32)
+    n = C.shape[0]
 
     t0 = time.perf_counter()
-    # ---- coarsen: stack of (C, M, flow_pairs, sys_pairs), finest first.
-    stack = []
-    Cl, Ml = C, M
-    while (Cl.shape[0] > cfg.coarse_n and Cl.shape[0] % 2 == 0
-           and len(stack) < cfg.max_levels):
-        fp = heavy_edge_matching(Cl)
-        sp = closest_pair_matching(Ml)
-        stack.append((Cl, Ml, fp, sp))
-        Cl, Ml = coarsen(Cl, Ml, fp, sp)
+    # ---- coarsen: stack of (C, M, flow_pairs, sys_pairs, pinned), finest
+    # first, and each level's flows at its device order.
+    with span("ml.coarsen", n=n) as coarsening:
+        stack = []
+        Cl, Ml = C, M
+        while Cl.shape[0] > cfg.coarse_n and len(stack) < cfg.max_levels:
+            fp, sp, pinned = match_level(Cl, Ml)
+            stack.append((Cl, Ml, fp, sp, pinned))
+            Cl, Ml = coarsen(Cl, Ml, fp, sp)
+        finest = _level_flows(C, cfg)
+        flows = [finest] + [_level_flows(s[0], cfg) for s in stack[1:]]
+        coarsening.set(levels=len(stack), coarse_n=Cl.shape[0],
+                       ell_width=finest.max_degree
+                       if isinstance(finest, sparse.SparseFlows) else None)
 
     # ---- coarse solve (dense: at coarse_n the dense path is the fast one).
+    nc = Cl.shape[0]
+    order = level_order(nc)
+    nv = None if order == nc else np.int32(nc)
     kc = jax.random.fold_in(key, 0)
+    Cc, Mc = jnp.asarray(_padded(Cl, order)), jnp.asarray(_padded(Ml, order))
     if cfg.algorithm == "psa":
-        p, _, _ = annealing.run_psa(jnp.asarray(Cl), jnp.asarray(Ml), kc,
-                                    cfg.coarse_sa, cfg.num_processes)
+        out = annealing.run_psa(
+            Cc, Mc, kc, cfg.coarse_sa, cfg.num_processes, n_valid=nv,
+            counts=annealing.resolved_loop(cfg.coarse_sa, order) == "event")
     else:
-        p, _, _ = genetic.run_pga(jnp.asarray(Cl), jnp.asarray(Ml), kc,
-                                  cfg.coarse_ga, cfg.num_processes)
-    p = np.asarray(p)
+        out = genetic.run_pga(Cc, Mc, kc, cfg.coarse_ga, cfg.num_processes,
+                              n_valid=nv)
+    p = _fetch(out, nc, span)
     coarse_f = _np_objective(Cl, Ml, p)
 
-    # ---- prolong + warm-started sparse refinement, coarsest to finest.
+    # ---- prolong + warm-started refinement, coarsest to finest.
     levels = []
-    for li, (Cl, Ml, fp, sp) in enumerate(reversed(stack)):
-        p = prolong_perm(p, fp, sp)
-        f_pro = _np_objective(Cl, Ml, p)
-        Cs = sparse.prepare_flows(Cl, cfg.refine_sa.flows)
-        kr = jax.random.fold_in(key, 1 + li)
-        p_ref, _, _ = annealing.run_psa(
-            Cs, jnp.asarray(Ml), kr, cfg.refine_sa, cfg.num_processes,
-            init_perm=jnp.asarray(p, jnp.int32))
-        p = np.asarray(p_ref)
-        f_ref = _np_objective(Cl, Ml, p)
-        levels.append(LevelInfo(n=Cl.shape[0], nnz=int((Cl != 0).sum()),
+    keep = annealing.resolved_loop(cfg.refine_sa) == "event"
+    for li, ((Cl, Ml, fp, sp, pinned), Cs) in enumerate(
+            zip(reversed(stack), reversed(flows))):
+        nl, nnz = Cl.shape[0], int(np.count_nonzero(Cl))
+        with span("ml.level", n=nl, nnz=nnz):
+            p = prolong_perm(p, fp, sp, pinned)
+            f_pro = _np_objective(Cl, Ml, p)
+            order = level_order(nl)
+            out = annealing.run_psa(
+                Cs, jnp.asarray(_padded(Ml, order)),
+                jax.random.fold_in(key, 1 + li), cfg.refine_sa,
+                cfg.num_processes,
+                n_valid=None if order == nl else np.int32(nl),
+                init_perm=jnp.asarray(_padded_perm(p, order)), counts=keep)
+            p = _fetch(out, nl, span)
+            f_ref = _np_objective(Cl, Ml, p)
+        levels.append(LevelInfo(n=nl, nnz=nnz,
                                 f_prolonged=f_pro, f_refined=f_ref))
 
     # ---- final polish on the finest level (sparse 2-swap descent).
     if cfg.final_polish_rounds > 0:
-        Cs = sparse.prepare_flows(C, cfg.refine_sa.flows)
-        p_pol, _ = mapping.polish(Cs, jnp.asarray(M),
-                                  jnp.asarray(p, jnp.int32),
-                                  jax.random.fold_in(key, 7),
-                                  rounds=cfg.final_polish_rounds)
-        p = np.asarray(p_pol)
+        order = level_order(n)
+        out = mapping.polish_jit(
+            finest, jnp.asarray(_padded(M, order)),
+            jnp.asarray(_padded_perm(p, order)), jax.random.fold_in(key, 7),
+            rounds=cfg.final_polish_rounds,
+            n_valid=None if order == n else np.int32(n))
+        p = _fetch(out, n, span)
     f = _np_objective(C, M, p)
+    f_identity = _np_objective(C, M, np.arange(n))
+    if f > f_identity:          # never worse than the placement it replaces
+        p, f = np.arange(n), f_identity
     return MultilevelResult(perm=p.astype(np.int32), objective=f,
                             coarse_objective=coarse_f, levels=tuple(levels),
                             seconds=time.perf_counter() - t0)

@@ -8,7 +8,7 @@ delta evaluation O(n²) regardless of how many flows are actually nonzero.
 
 * **Padded row blocks, static shapes.**  Row k keeps its nonzero column
   ids in ``cols[k, :]`` (ascending) and their values in ``vals[k, :]``,
-  both padded to a shared width ``D`` = max row degree.  Padding entries
+  both padded to a shared width ``D`` >= the max row degree.  Padding entries
   carry value 0 (their column id is an arbitrary in-range index), so
   every consumer can process full (N, D) blocks without ragged logic —
   the shape is static, which keeps the structure jit-traceable,
@@ -23,13 +23,16 @@ delta evaluation O(n²) regardless of how many flows are actually nonzero.
   (the ``.shape`` property mimics the dense (N, N) view the solvers
   consult for sizes).
 
-Conversion (:func:`from_dense`) is host-side numpy — the padded width is
-data-dependent, so it cannot run under jit; convert once per instance,
-then everything downstream is traced.  :func:`to_dense` is traceable and
+Conversion (:func:`from_dense`) is host-side numpy — which entries are
+nonzero is data, so it cannot run under jit; convert once per instance,
+then everything downstream is traced.  :func:`ell_width` gives a width
+fixed by the order and the density class, so that instances of one
+order share their programs.  :func:`to_dense` is traceable and
 exact: scattering the padded blocks back adds only zeros on padding.
 """
 from __future__ import annotations
 
+import math
 from typing import NamedTuple, Optional, Tuple
 
 import jax
@@ -37,6 +40,8 @@ import jax.numpy as jnp
 import numpy as np
 
 Array = jax.Array
+
+_LANE = 128     # the sparse kernels take ELL rows in 128-lane blocks
 
 
 class SparseFlows(NamedTuple):
@@ -87,6 +92,32 @@ def max_degree(C) -> int:
     d = max(int(nz.sum(axis=-1).max(initial=0)),
             int(nz.sum(axis=-2).max(initial=0)))
     return max(d, 1)
+
+
+def ell_width(C, order: int) -> int:
+    """The padded width for flows ``C`` held at ``order`` (>= C's order):
+    fixed by the order and ``C``'s density class, never by its densest
+    row, so every instance of one recipe at one order shares a program.
+
+    The class is the density (nonzeros over n²) rounded up to a power of
+    two; the width is 1.5 times the class's mean row at ``order``, in
+    whole 128-lane blocks, capped at ``order``.  Flows denser than their
+    class allows (a row above that width) get the next power of two that
+    holds their densest row: still a short ladder, never this instance's
+    own maximum.  docs/DESIGN.md §10 gives the margins.
+    """
+    A = np.asarray(C)
+    n = A.shape[-1]
+    nnz = int(np.count_nonzero(A))
+    if nnz == 0:
+        return min(order, _LANE)
+    density_class = 2.0 ** math.ceil(math.log2(nnz / (n * n)))
+    width = min(order, -(-math.ceil(1.5 * density_class * order) // _LANE)
+                * _LANE)
+    d = max_degree(A)
+    if d > width:
+        width = min(order, 1 << (d - 1).bit_length())
+    return width
 
 
 def _rows_to_ell(A: np.ndarray, width: int):
@@ -149,15 +180,3 @@ def mask_flows_sparse(S: SparseFlows, n_valid: Array) -> SparseFlows:
     w = (jnp.arange(S.n) < n_valid).astype(S.vals.dtype)
     return S._replace(vals=S.vals * w[:, None] * w[S.cols],
                       vals_t=S.vals_t * w[:, None] * w[S.cols_t])
-
-
-def prepare_flows(C, flows: str, width: Optional[int] = None):
-    """Host-side flow-representation hook for the solver configs'
-    ``flows`` field: ``"sparse"`` converts a dense matrix once (a no-op
-    if ``C`` already is :class:`SparseFlows`); ``"dense"`` passes
-    through.  Call *outside* jit — conversion shapes depend on data."""
-    if flows not in ("dense", "sparse"):
-        raise ValueError(f"flows must be 'dense' or 'sparse', got {flows!r}")
-    if flows == "sparse" and not isinstance(C, SparseFlows):
-        return from_dense(C, width)
-    return C

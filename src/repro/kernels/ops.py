@@ -276,6 +276,15 @@ def delta_order(n: int) -> int:
     return n if delta_form(n) == "reference" else padded_order(n)
 
 
+def sparse_delta_order(n: int) -> int:
+    """The order :func:`qap_delta_sparse` works at for order-``n``
+    instances: the kernel's lane-padded order on a TPU up to
+    ``MAX_SPARSE_KERNEL_N``, ``n`` on the reference path."""
+    if _on_tpu() and padded_order(n) <= MAX_SPARSE_KERNEL_N:
+        return padded_order(n)
+    return n
+
+
 # --------------------------------------------------------- fused solver steps
 
 def fused_step_fits(n: int) -> bool:

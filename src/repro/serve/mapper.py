@@ -49,7 +49,8 @@ resource manager's timeout.  The engine is built around that contract:
   8. Orders above every dense bucket route by *large bucket*
      (512/1024/4096 by default) to the sparse + multilevel pipeline
      (``core.multilevel``) once they reach ``multilevel_min_n`` — the
-     dense O(n²) ceiling stops applying (docs/DESIGN.md §10); smaller
+     dense O(n²) ceiling stops applying, and each level runs at the
+     next power of two of its order (docs/DESIGN.md §10); smaller
      oversize orders keep the unpadded exact-size path.
 
 Queue, cache, and stats are thread-safe; solves are serialized by a
@@ -87,7 +88,7 @@ import threading
 import time
 from collections import OrderedDict
 from dataclasses import dataclass, replace
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import Dict, List, Mapping, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -102,8 +103,9 @@ DEFAULT_BUCKETS = (32, 64, 128)
 
 # Routing labels for the sparse/multilevel path: orders above the dense
 # buckets (and >= multilevel_min_n) group under the smallest large bucket
-# that holds them and solve via core.multilevel at exact size — the dense
-# O(n²) solvers never see these instances (docs/DESIGN.md §10).
+# that holds them and solve via core.multilevel, each level at the power
+# of two of its order — the dense O(n²) solvers never see these instances
+# (docs/DESIGN.md §10).
 LARGE_BUCKETS = (512, 1024, 4096)
 
 ALGORITHMS = ("psa", "pga", "pca")
@@ -338,17 +340,6 @@ def _keeps_counts(cfg: annealing.SAConfig, bucket: int) -> bool:
     return annealing.resolved_loop(cfg, bucket) == "event"
 
 
-def _loop_counts(counts: annealing.LoopCounts) -> Dict[str, int]:
-    """A wave's solver counts, for its ``engine.fetch`` span: the rounds
-    the batched event loop ran (at each level, as many as its slowest
-    lane), the rounds its lanes ran, the lanes and the accepted moves."""
-    rounds = np.asarray(counts.rounds)
-    levels = rounds.reshape(-1, rounds.shape[-1])
-    return {"rounds_executed": int(levels.max(axis=0).sum()),
-            "lane_rounds": int(rounds.sum()), "lanes": levels.shape[0],
-            "accepts": int(np.asarray(counts.accepts).sum())}
-
-
 def _tighten_sa(cfg: annealing.SAConfig) -> annealing.SAConfig:
     """Reduced-budget SA for the tight deadline tier (~1/4 the work)."""
     return replace(cfg,
@@ -390,20 +381,25 @@ class MappingEngine:
                  instance_axis: str = batch_sharded.DEFAULT_AXIS,
                  large_buckets: Sequence[int] = LARGE_BUCKETS,
                  multilevel_min_n: int = 256,
-                 multilevel_cfg: Optional[multilevel.MultilevelConfig] = None,
+                 multilevel_cfg: Optional[multilevel.MultilevelConfig
+                                          | Mapping] = None,
                  max_pending: Optional[int] = None):
         self.buckets = tuple(sorted(int(b) for b in buckets))
         if not self.buckets:
             raise ValueError("need at least one size bucket")
         # Large buckets are routing labels, not padded sizes: an order
         # above every dense bucket (and >= multilevel_min_n) groups under
-        # its large bucket and solves through core.multilevel at exact
-        # size.  Orders below the threshold keep the seed-era unpadded
+        # its large bucket and solves through core.multilevel (which pads
+        # each level itself).  Orders below the threshold keep the unpadded
         # exact-size path (bucket None).  A value also present in the
         # dense buckets stays dense — bucket_for() wins.
         self.large_buckets = tuple(sorted(int(b) for b in large_buckets))
         self._large_set = frozenset(self.large_buckets) - frozenset(self.buckets)
         self.multilevel_min_n = int(multilevel_min_n)
+        # a configuration file gives the multilevel settings as a mapping
+        # of MultilevelConfig's fields
+        if isinstance(multilevel_cfg, Mapping):
+            multilevel_cfg = multilevel.MultilevelConfig(**multilevel_cfg)
         self.multilevel_cfg = multilevel_cfg or multilevel.MultilevelConfig()
         self.cache_size = int(cache_size)
         self.num_processes = int(num_processes)
@@ -586,10 +582,12 @@ class MappingEngine:
         Returns the number of programs compiled (also accumulated in
         ``stats.warmup_programs``).
 
-        Only the dense padded buckets are warmable: the multilevel large
-        buckets solve at exact size with data-dependent coarsening shapes,
-        so their programs compile on first dispatch (the persistent JAX
-        compilation cache still amortizes repeats across processes).
+        Only the dense padded buckets are warmed here.  The multilevel
+        large buckets run programs fixed by each level's order
+        (``multilevel.level_order``), so they compile on the first solve
+        of each order's power of two and never again in the process (the
+        persistent JAX compilation cache amortizes them across
+        processes).
         """
         buckets = tuple(self.buckets if buckets is None else
                         sorted(int(b) for b in buckets))
@@ -1114,7 +1112,7 @@ class MappingEngine:
             perms = np.asarray(perms)
             fs = np.asarray(fs)
             if counts is not None:
-                fetch.set(**_loop_counts(counts))
+                fetch.set(**annealing.loop_totals(counts))
         self._device_s += dispatch.dur + fetch.dur
         out = []
         for i, req in enumerate(reqs):
@@ -1165,16 +1163,26 @@ class MappingEngine:
 
     def _solve_multilevel(self, req: MapRequest) -> Tuple[np.ndarray, float]:
         """Large-bucket instances run the coarsen → map → refine pipeline
-        (``core.multilevel``) at exact size: host-side heavy-edge
-        coarsening, dense coarse solve, warm-started *sparse* refinement
-        per level — O(nnz) per candidate, so orders far beyond the dense
-        buckets stay schedulable.  The tier's solver budgets do not apply;
+        (``core.multilevel``): host-side heavy-edge coarsening of any
+        order, dense coarse solve, warm-started *sparse* refinement per
+        level — O(nnz) per candidate, so orders far beyond the dense
+        buckets stay schedulable — each level on programs fixed by its
+        order.  The tier's solver budgets do not apply;
         ``multilevel_cfg`` governs (and is folded into the cache digest
-        for these orders)."""
+        for these orders).  The pipeline's waits for the device are
+        ``engine.fetch`` spans inside this dispatch's span."""
+        n = req.C.shape[0]
+        # the finest level's device order, and its lanes on the kernel path
+        with telemetry.span(
+                "engine.pack", bucket=self._route(n), rows=1, padded_rows=1,
+                orders=n, kernel_order=kernel_ops.sparse_delta_order(
+                    multilevel.level_order(n))):
+            C = np.asarray(req.C, np.float32)
+            M = np.asarray(req.M, np.float32)
         with telemetry.span("engine.dispatch", path="multilevel") as dispatch:
             res = multilevel.solve_multilevel(
-                req.C, req.M, jax.random.PRNGKey(req.seed),
-                self.multilevel_cfg)
+                C, M, jax.random.PRNGKey(req.seed), self.multilevel_cfg,
+                span=telemetry.span)
             out = np.asarray(res.perm, np.int32), float(res.objective)
         with self._lock:
             self.stats.solver_batches += 1

@@ -388,10 +388,13 @@ def test_multilevel_never_worse_than_coarse():
 
 
 def test_multilevel_odd_order_skips_coarsening():
+    """An odd order no longer skips coarsening: one process is pinned on
+    one node and the rest halves, 9 -> 4."""
     C, M = instance(9, 50)
     res = multilevel.solve_multilevel(C, M, jax.random.PRNGKey(1),
                                       replace(ML_TINY, coarse_n=4))
-    assert res.levels == ()                   # odd order: direct solve
+    assert [lv.n for lv in res.levels] == [9]     # odd order: coarsened
+    assert res.levels[0].f_refined <= res.levels[0].f_prolonged
     assert sorted(np.asarray(res.perm).tolist()) == list(range(9))
 
 
@@ -477,3 +480,20 @@ def test_engine_digest_tags_multilevel_route():
     small = MapRequest(job_id="k", C=inst.C[:8, :8], M=inst.M[:8, :8],
                        algorithm="psa", seed=0)
     assert eng_a.digest(small) == eng_b.digest(small)   # dense route: no tag
+
+
+def test_engine_takes_multilevel_settings_as_a_mapping():
+    from repro.serve.mapper import MapRequest
+    inst = exact.make_torus((4, 4))
+    kw = dict(buckets=(8,), large_buckets=(16,), multilevel_min_n=16,
+              num_processes=2, sa_cfg=SA_SMALL)
+    fields = {"coarse_n": ML_TINY.coarse_n, "coarse_sa": ML_TINY.coarse_sa,
+              "refine_sa": ML_TINY.refine_sa,
+              "final_polish_rounds": ML_TINY.final_polish_rounds}
+    eng_a = MappingEngine(multilevel_cfg=ML_TINY, **kw)
+    eng_b = MappingEngine(multilevel_cfg=fields, **kw)
+    assert eng_b.multilevel_cfg == ML_TINY
+    req = MapRequest(job_id="j", C=inst.C, M=inst.M, algorithm="psa", seed=0)
+    assert eng_a.digest(req) == eng_b.digest(req)
+    with pytest.raises(TypeError):
+        MappingEngine(multilevel_cfg={"coarse": 8}, **kw)

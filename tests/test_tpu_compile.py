@@ -126,11 +126,15 @@ def test_sparse_delta_kernel_compiles(one_chip, n, d, lead):
 
 
 @pytest.mark.parametrize("algorithm", ["psa", "pga", "pca", "polish",
-                                       "refine"])
+                                       "refine", "refine_padded",
+                                       "ml_polish"])
 def test_served_solver_programs_compile(one_chip, monkeypatch, algorithm):
     """The engine's bucket-128 solver programs (default budgets) and a
     multilevel sparse refinement level, traced down the TPU dispatch
-    path: every XLA op and kernel in them must compile for v5e."""
+    path: every XLA op and kernel in them must compile for v5e.  The
+    padded ones are the multilevel programs of a level below its power of
+    two (``n_valid``), with the event loop's counts: a refinement level at
+    order 1024 and ELL width 768, and the final polish at 2048 and 384."""
     eng = MappingEngine()
     monkeypatch.setattr(ops, "_on_tpu", lambda: True)
     B, n = 4, 128
@@ -153,6 +157,18 @@ def test_served_solver_programs_compile(one_chip, monkeypatch, algorithm):
             _spec(one_chip, (2,), jnp.uint32),
             eng.multilevel_cfg.refine_sa, 2,
             init_perm=_spec(one_chip, (1024,), jnp.int32)),
+        "refine_padded": lambda: annealing.run_psa.lower(
+            _flows(one_chip, (), 1024, 768), _spec(one_chip, (1024, 1024)),
+            _spec(one_chip, (2,), jnp.uint32),
+            eng.multilevel_cfg.refine_sa, 2,
+            n_valid=_spec(one_chip, (), jnp.int32),
+            init_perm=_spec(one_chip, (1024,), jnp.int32), counts=True),
+        "ml_polish": lambda: mapping.polish_jit.lower(
+            _flows(one_chip, (), 2048, 384), _spec(one_chip, (2048, 2048)),
+            _spec(one_chip, (2048,), jnp.int32),
+            _spec(one_chip, (2,), jnp.uint32),
+            rounds=eng.multilevel_cfg.final_polish_rounds,
+            n_valid=_spec(one_chip, (), jnp.int32)),
     }
     # Trace caches key on signatures only: drop TPU-path traces so they
     # never leak into (or come from) the CPU-path tests.
