@@ -961,6 +961,7 @@ class MappingEngine:
             for (bucket, algorithm, tier), by_digest in groups.items():
                 heads = [ps[0] for ps in by_digest.values()]
                 self._device_s = 0.0
+                baselines = [None] * len(heads)
                 try:
                     with self._lock:
                         warms = [self._warm_perm(p.req) for p in heads]
@@ -971,8 +972,10 @@ class MappingEngine:
                         # Multilevel path: per-head host-side coarsening +
                         # warm-started sparse refinement; shape-tier warm
                         # starts are ignored (the coarse solve is the seed).
-                        solved = [self._solve_multilevel(p.req)
-                                  for p in heads]
+                        results = [self._solve_multilevel(p.req)
+                                   for p in heads]
+                        solved = [r[:2] for r in results]
+                        baselines = [r[2] for r in results]
                         warms = [None] * len(heads)
                     else:
                         solved = self._solve_bucket(
@@ -989,15 +992,16 @@ class MappingEngine:
                 with telemetry.span("engine.respond"), self._lock:
                     self.stats.warm_starts += sum(w is not None
                                                   for w in warms)
-                    for key, (perm, objective), w, p0 in zip(
-                            by_digest, solved, warms, heads):
+                    for key, (perm, objective), w, base, p0 in zip(
+                            by_digest, solved, warms, baselines, heads):
                         self._cache_put(key, self.shape_digest(p0.req),
                                         perm, objective)
                         for p in by_digest[key]:
                             resp = self._respond(
                                 p, perm, objective, bucket=bucket,
                                 cached=False, seconds=per_instance,
-                                batch_size=total, warm_start=w is not None)
+                                batch_size=total, warm_start=w is not None,
+                                baseline=base)
                             if p.future._resolve(resp):
                                 responses[p.req.job_id] = resp
                             else:            # cancelled mid-solve
@@ -1008,11 +1012,16 @@ class MappingEngine:
 
     def _respond(self, p: _Pending, perm: np.ndarray, objective: float,
                  bucket: Optional[int], cached: bool, seconds: float,
-                 batch_size: int, warm_start: bool = False) -> MapResponse:
+                 batch_size: int, warm_start: bool = False,
+                 baseline: Optional[float] = None) -> MapResponse:
+        """``baseline``, the identity's F, when the solve already has it
+        (a multilevel solve's, over the flows' nonzeros); else it is
+        computed here."""
         req = p.req
         n = req.C.shape[0]
-        baseline = float((np.asarray(req.C, np.float64)
-                          * np.asarray(req.M, np.float64)).sum())
+        if baseline is None:
+            baseline = float((np.asarray(req.C, np.float64)
+                              * np.asarray(req.M, np.float64)).sum())
         if objective > baseline:
             # A mapping must never be worse than the trivial placement.
             perm, objective = np.arange(n, dtype=np.int32), baseline
@@ -1161,7 +1170,8 @@ class MappingEngine:
         self._device_s += dispatch.dur + fetch.dur
         return p, f
 
-    def _solve_multilevel(self, req: MapRequest) -> Tuple[np.ndarray, float]:
+    def _solve_multilevel(self, req: MapRequest
+                          ) -> Tuple[np.ndarray, float, float]:
         """Large-bucket instances run the coarsen → map → refine pipeline
         (``core.multilevel``): host-side heavy-edge coarsening of any
         order, dense coarse solve, warm-started *sparse* refinement per
@@ -1170,7 +1180,8 @@ class MappingEngine:
         order.  The tier's solver budgets do not apply;
         ``multilevel_cfg`` governs (and is folded into the cache digest
         for these orders).  The pipeline's waits for the device are
-        ``engine.fetch`` spans inside this dispatch's span."""
+        ``engine.fetch`` spans inside this dispatch's span.  Returns the
+        perm, its F and the identity's F (the response's baseline)."""
         n = req.C.shape[0]
         # the finest level's device order, and its lanes on the kernel path
         with telemetry.span(
@@ -1183,7 +1194,8 @@ class MappingEngine:
             res = multilevel.solve_multilevel(
                 C, M, jax.random.PRNGKey(req.seed), self.multilevel_cfg,
                 span=telemetry.span)
-            out = np.asarray(res.perm, np.int32), float(res.objective)
+            out = (np.asarray(res.perm, np.int32), float(res.objective),
+                   float(res.identity_objective))
         with self._lock:
             self.stats.solver_batches += 1
             self.stats.solver_calls += 1

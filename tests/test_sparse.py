@@ -78,6 +78,72 @@ def test_from_dense_leading_batch():
     np.testing.assert_array_equal(np.asarray(sparse.to_dense(S)), Cs)
 
 
+def _asymmetric(n, seed, density):
+    """Integer flows, asymmetric, with a nonzero diagonal and one empty
+    row and column."""
+    rng = np.random.default_rng(seed)
+    C = ((rng.random((n, n)) < density)
+         * rng.integers(1, 10, (n, n))).astype(np.float32)
+    C[n // 2, :] = 0
+    C[:, n // 3] = 0
+    return C
+
+
+@pytest.mark.parametrize("n,order,density,symmetric", [
+    (16, 16, 0.3, True), (40, 64, 0.2, False), (97, 128, 0.1, True),
+    (130, 256, 0.05, False), (12, 16, 0.0, True), (33, 64, 1.0, False)])
+def test_from_nonzeros_matches_from_dense(n, order, density, symmetric):
+    """The ELL blocks built from the nonzeros hold from_dense's stored
+    entries of the padded matrix, in its order, with equal degrees;
+    padding slots hold value 0 and a valid column."""
+    C = _asymmetric(n, n, density)
+    if symmetric:
+        C = np.triu(C, 1) + np.triu(C, 1).T
+    flows = sparse.nonzeros(C)
+    width = max(sparse.max_degree(C), 4)
+    got = sparse.from_nonzeros(flows, order, width)
+    want = sparse.from_dense(np.pad(C, ((0, order - n),) * 2), width)
+    assert got.shape == want.shape == (order, order)
+    for cols, vals, deg, w_cols, w_vals, w_deg in (
+            (got.cols, got.vals, got.deg, want.cols, want.vals, want.deg),
+            (got.cols_t, got.vals_t, got.deg_t, want.cols_t, want.vals_t,
+             want.deg_t)):
+        np.testing.assert_array_equal(deg, w_deg)
+        assert cols.dtype == w_cols.dtype and vals.dtype == w_vals.dtype
+        stored = np.arange(width)[None, :] < np.asarray(deg)[:, None]
+        np.testing.assert_array_equal(np.asarray(cols)[stored],
+                                      np.asarray(w_cols)[stored])
+        np.testing.assert_array_equal(np.asarray(vals)[stored],
+                                      np.asarray(w_vals)[stored])
+        assert (np.asarray(vals)[~stored] == 0).all()
+        assert ((np.asarray(cols) >= 0) & (np.asarray(cols) < order)).all()
+    np.testing.assert_array_equal(np.asarray(sparse.to_dense(got)),
+                                  np.pad(C, ((0, order - n),) * 2))
+    if flows.nnz:
+        with pytest.raises(ValueError):
+            sparse.from_nonzeros(flows, order, sparse.max_degree(C) - 1)
+
+
+def test_coalesce_sums_like_a_dense_accumulation():
+    """Duplicates summed in float64 in their given order, rounded to
+    float32; sums of zero and nothing else dropped; row-major."""
+    rng = np.random.default_rng(3)
+    n, k = 37, 4000
+    rows, cols = rng.integers(0, n, k), rng.integers(0, n, k)
+    vals = rng.normal(size=k) * rng.integers(0, 2, k)
+    rows[(rows == 5) & (cols == 7)] = 6
+    rows[:20], cols[:20] = 5, 7                     # these cancel to 0
+    vals[:10], vals[10:20] = 0.5, -0.5
+    acc = np.zeros((n, n))
+    np.add.at(acc, (rows, cols), vals)
+    want = acc.astype(np.float32)
+    got = sparse.coalesce(n, rows, cols, vals)
+    np.testing.assert_array_equal(got.dense(), want)
+    assert got.nnz == np.count_nonzero(want) and want[5, 7] == 0
+    flat = got.rows.astype(np.int64) * n + got.cols
+    assert (np.diff(flat) > 0).all()
+
+
 def test_mask_flows_sparse_matches_dense():
     C, _ = _sparse_instance(16, 1, 0.4)
     S = sparse.from_dense(C)
@@ -462,8 +528,11 @@ def test_engine_multilevel_solve_and_cache():
     f = float((inst.C.astype(np.float64)
                * inst.M.astype(np.float64)[np.ix_(p, p)]).sum())
     assert r.objective == pytest.approx(f)
+    # the solve's identity F over the nonzeros is the dense sum exactly
+    assert r.baseline == float((inst.C.astype(np.float64)
+                                * inst.M.astype(np.float64)).sum())
     r2 = eng.map_one(inst.C, inst.M, seed=1, cache_seed=True)
-    assert r2.cached
+    assert r2.cached and r2.baseline == r.baseline
     np.testing.assert_array_equal(np.asarray(r2.perm), p)
 
 
